@@ -2,7 +2,7 @@
 
 Prints ``name,us_per_call,derived`` CSV rows (benchmarks.common.emit).
 Roofline terms come from the dry-run artifacts — see
-``python -m repro.launch.roofline`` (EXPERIMENTS.md §Roofline).
+``python -m repro.launch.roofline``.
 """
 
 from __future__ import annotations
@@ -30,6 +30,8 @@ def main() -> None:
             "benchmarks/run.py: the sync-sanitizer is active (debug_sync "
             "engine live or REPRO_DEBUG_SYNC=1) — refusing to emit measured "
             "numbers; unset REPRO_DEBUG_SYNC / close debug engines first")
+    from repro.launch.compile_cache import configure_compile_cache
+    configure_compile_cache()
     from benchmarks import (common, engine_audit, fig4_5_overheads,
                             fig7_8_desert, fig10_11_evals, fig13_pipeline,
                             fig14_quality, fig15_latency, fig16_17_breakdown,

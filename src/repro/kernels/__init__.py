@@ -1,19 +1,3 @@
 # OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
 # for compute hot-spots the paper itself optimizes with a custom
 # kernel. Leave this package empty if the paper has none.
-
-from __future__ import annotations
-
-from jax.experimental.pallas import tpu as pltpu
-
-
-def tpu_compiler_params(**kwargs):
-    """Version shim over the pinned JAX's Pallas-TPU compiler params.
-
-    Newer JAX exposes ``pltpu.CompilerParams``; the pinned 0.4.x series
-    calls the same dataclass ``TPUCompilerParams``.  Every kernel in this
-    package routes through this helper instead of naming either directly.
-    """
-    cls = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams")
-    return cls(**kwargs)

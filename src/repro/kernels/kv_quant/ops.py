@@ -18,5 +18,15 @@ def kv_dequant(data: jax.Array, scale: jax.Array, *, codec: str = "int4",
     if impl == "ref":
         fn = dequant_int4_ref if codec == "int4" else dequant_int8_ref
         return fn(data, scale, out_dtype)
-    return kv_dequant_pallas(data, scale, codec=codec, out_dtype=out_dtype,
-                             interpret=(impl == "interpret"))
+    # pad the chunk count to a power of two: the upload delta varies every
+    # round, and each distinct grid would compile its own kernel
+    N = data.shape[0]
+    pad = (1 << max(0, N - 1).bit_length()) - N
+    if pad:
+        data = jnp.concatenate(
+            [data, jnp.zeros((pad, *data.shape[1:]), data.dtype)])
+        scale = jnp.concatenate(
+            [scale, jnp.zeros((pad, *scale.shape[1:]), scale.dtype)])
+    out = kv_dequant_pallas(data, scale, codec=codec, out_dtype=out_dtype,
+                            interpret=(impl == "interpret"))
+    return out[:N] if pad else out

@@ -36,6 +36,14 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def _row_tile(N: int, tile_n: int) -> int:
+    """Row tile of the Pallas grid: a multiple of 128 (codes ride along
+    the lanes), no larger than ``N`` rounded up to one."""
+    if tile_n % 128:
+        raise ValueError(f"tile_n={tile_n} must be a multiple of 128")
+    return min(tile_n, -(-N // 128) * 128)
+
+
 def pq_assign(x: jax.Array, cb: jax.Array, *, impl: Optional[str] = None,
               tile_n: int = 256) -> jax.Array:
     """x: (m, N, dsub); cb: (m, K, dsub) -> codes (m, N) int32.
@@ -47,7 +55,7 @@ def pq_assign(x: jax.Array, cb: jax.Array, *, impl: Optional[str] = None,
     if impl == "ref":
         return pq_assign_ref(x, cb)
     N = x.shape[1]
-    tile = min(tile_n, max(8, N))
+    tile = _row_tile(N, tile_n)
     pad = (-N) % tile
     if pad:
         x = jnp.concatenate(
@@ -66,7 +74,7 @@ def pq_update(x: jax.Array, codes: jax.Array, n_centroids: int, *,
     if impl == "ref":
         return pq_update_ref(x, codes, n_centroids)
     N = x.shape[1]
-    tile = min(tile_n, max(8, N))
+    tile = _row_tile(N, tile_n)
     pad = (-N) % tile
     if pad:
         x = jnp.concatenate(

@@ -3,6 +3,8 @@
 Uses the SAME ``|c_k|^2 - 2 x.c_k`` distance expression as the Pallas
 kernel so argmin tie-breaking (first minimal index) matches exactly —
 the kernel tests compare codes with ``assert_array_equal``, not allclose.
+Both sides ask for full f32 matmul precision: a TPU's default f32 dot
+rounds its inputs to bf16, which would move near-tie codes.
 """
 
 from __future__ import annotations
@@ -18,7 +20,8 @@ def pq_assign_ref(x: jax.Array, cb: jax.Array) -> jax.Array:
     x = jnp.asarray(x, jnp.float32)
     cb = jnp.asarray(cb, jnp.float32)
     d = jnp.sum(cb * cb, axis=-1)[:, None, :] \
-        - 2.0 * jnp.einsum("mnd,mkd->mnk", x, cb)
+        - 2.0 * jnp.einsum("mnd,mkd->mnk", x, cb,
+                           precision=jax.lax.Precision.HIGHEST)
     return jnp.argmin(d, axis=-1).astype(jnp.int32)
 
 
@@ -32,6 +35,7 @@ def pq_update_ref(x: jax.Array, codes: jax.Array, n_centroids: int
     x = jnp.asarray(x, jnp.float32)
     onehot = (jnp.asarray(codes, jnp.int32)[..., None]
               == jnp.arange(n_centroids)[None, None, :]).astype(jnp.float32)
-    sums = jnp.einsum("mnk,mnd->mkd", onehot, x)
+    sums = jnp.einsum("mnk,mnd->mkd", onehot, x,
+                      precision=jax.lax.Precision.HIGHEST)
     counts = jnp.sum(onehot, axis=1)
     return sums, counts

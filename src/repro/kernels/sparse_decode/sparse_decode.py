@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
 
 NEG = float("-inf")
 
@@ -113,7 +112,7 @@ def sparse_decode_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shape,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(ids, length.reshape(1), q, k, v)

@@ -31,7 +31,7 @@ from repro.configs import ASSIGNED, SHAPES, get_config, get_shape
 from repro.configs.base import ArchConfig, ShapeCfg
 from repro.launch import steps as stp
 from repro.launch.hlo_costing import analyze
-from repro.launch.mesh import make_production_mesh, set_mesh
+from repro.launch.mesh import make_production_mesh
 from repro.models import lm
 
 
@@ -61,7 +61,7 @@ def lower_cell(arch: str, shape_name: str, mesh, *, save_hlo: Optional[str]
                  "mesh": "x".join(map(str, mesh.devices.shape)),
                  "n_devices": int(n_dev), "kind": shape.kind}
     t0 = time.time()
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             jitted, ss, bspec = stp.make_jitted_train_step(
                 cfg, mesh, stp.TrainCfg(), shape)

@@ -11,28 +11,10 @@ intra-pod).
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
-
-try:  # jax >= 0.6 takes explicit axis types; the pinned 0.4.x does not
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - exercised on the pinned JAX
-    AxisType = None
-
-
-def set_mesh(mesh: Mesh):
-    """Context manager activating ``mesh`` for tracing.
-
-    ``jax.set_mesh`` on new JAX; on the pinned 0.4.x a ``Mesh`` is itself a
-    context manager with the equivalent thread-local effect.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+from jax.sharding import AxisType, Mesh
 
 
 def _mesh(shape, axes) -> Mesh:
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 

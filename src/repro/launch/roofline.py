@@ -1,4 +1,4 @@
-"""Roofline analysis from dry-run artifacts (EXPERIMENTS.md §Roofline).
+"""Roofline analysis from dry-run artifacts (``repro.launch.dryrun``).
 
 Per (arch × shape × mesh) cell:
     compute term    = HLO_FLOPs / (chips × 197e12)

@@ -31,7 +31,7 @@ from repro.core import sparse_attention as sa
 from repro.core.abstracts import Pyramid, build_pyramid, num_levels, update_pyramid
 from repro.models.common import rms_norm, rotate, softcap
 from repro.models.params import ParamDef
-from repro.sharding.ctx import constrain, constrain_priority, shard_map
+from repro.sharding.ctx import constrain, constrain_priority
 
 
 # ---------------------------------------------------------------------------
@@ -431,7 +431,7 @@ def gqa_decode(p, cfg: ArchConfig, kind: str, x: jax.Array,
         cache_spec = {
             n: P(db or None, ctx.seq_axes if len(ctx.seq_axes) > 1 else ctx.seq_axes[0],
                  *([None] * (cache[n].ndim - 2))) for n in names}
-        fn = shard_map(
+        fn = jax.shard_map(
             local_fn, mesh=ctx.mesh,
             in_specs=(P(db or None, None, None), P(db or None, None, None),
                       P(db or None, None, None), P(),
@@ -610,7 +610,7 @@ def mla_decode(p, cfg: ArchConfig, kind: str, x: jax.Array,
         seqs = ctx.seq_axes if len(ctx.seq_axes) > 1 else ctx.seq_axes[0]
         cache_spec = {n: P(db or None, seqs, *([None] * (cache[n].ndim - 2)))
                       for n in names}
-        fn = shard_map(
+        fn = jax.shard_map(
             local_fn, mesh=ctx.mesh,
             in_specs=(P(db or None, None, None), P(db or None, None, None),
                       P(db or None, None), P(db or None, None), P(),

@@ -114,7 +114,7 @@ def moe_apply(p, cfg: ArchConfig, x: jax.Array, *, no_drop: bool = False,
     # Root cause of the residual collective term is the FSDP layout
     # contracting expert matmuls over the data-sharded d dim plus the
     # per-microbatch expert-grad reductions; the proper fix (shard_map
-    # local grad accumulation) is recorded as future work in EXPERIMENTS.md.
+    # local grad accumulation) is future work.
     m = cfg.moe
     B, S, d = x.shape
     T = B * S

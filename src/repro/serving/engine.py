@@ -492,6 +492,9 @@ class BatchedLeoAMEngine:
         # _SeqState}); they keep their engine slot — the store row holds
         # their only full replica — but release every hot-tier resource
         self.suspended: Dict[int, _SeqState] = {}
+        # the last decode round's (V,) f32 logits per sequence (what its
+        # token was sampled from) — for checks against a dense reference
+        self.last_logits: Dict[int, np.ndarray] = {}
 
     @property
     def free_slots(self) -> int:
@@ -1479,6 +1482,7 @@ class BatchedLeoAMEngine:
             s.length += 1
             s.stats.append(round_stats[sid])
             out[sid] = int(np.argmax(logits[i]))
+        self.last_logits = dict(zip(order, logits))
         self._round_idx += 1
         if ecfg.sidecar_requant and (ecfg.disk_sidecar or ecfg.pq_abstracts):
             # background repack of append-dirtied sidecars and/or PQ

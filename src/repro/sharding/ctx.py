@@ -21,17 +21,6 @@ from repro.sharding import partition as pt
 _CTX: contextvars.ContextVar = contextvars.ContextVar("shard_ctx", default=None)
 
 
-def shard_map(f, *, mesh: Mesh, in_specs, out_specs, check_vma: bool = False):
-    """Version shim: ``jax.shard_map`` (new) vs ``jax.experimental.shard_map``
-    (pinned 0.4.x, where ``check_vma`` is spelled ``check_rep``)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
-
-
 @contextlib.contextmanager
 def sharding_ctx(mesh: Mesh, rules: Optional[Dict] = None):
     tok = _CTX.set((mesh, rules or pt.DEFAULT_RULES))

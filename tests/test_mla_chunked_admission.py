@@ -269,8 +269,19 @@ def test_mla_scheduler_chunked_admission_parity(seed):
 
 
 def test_mla_partial_engine_ingest_matches_whole_ingest(rng):
-    """Chunked MLA admission lands byte-identical replicas AND abstracts
-    in the tier store vs whole-prompt admission of the same prompt."""
+    """Chunked MLA admission lands the same replicas AND abstracts in the
+    tier store as whole-prompt admission of the same prompt, with
+    identical tier labels.
+
+    Values agree to 2**-10 relative (one fp16 ulp at the bottom of a
+    binade) or 2**-20 absolute, not bitwise: XLA:CPU picks its matmul
+    kernel by row count, so the latent projection ``x @ wkv_a`` over a
+    16-row chunk differs in the last f32 bit from the same rows inside the
+    64-row bucket (reproducible with a bare jitted ``x @ w``).  Those
+    last-bit differences carry through the layers at the f32 roundoff
+    level, and a few latents round to neighbouring fp16 values; a latent
+    that cancels to near zero keeps the absolute roundoff of its larger
+    summands (about 5e-7), hence the absolute term."""
     cfg, params = _setup()
     from repro.serving.engine import BatchedLeoAMEngine
     prompt = rng.randint(2, cfg.vocab_size, 57)
@@ -281,10 +292,12 @@ def test_mla_partial_engine_ingest_matches_whole_ingest(rng):
                                  _ecfg(prefill_chunk_tokens=16), max_seqs=1)
     chunked.begin_admission(prompt).drain()
     chunked.store.ingest_fence(0)
-    np.testing.assert_array_equal(np.asarray(whole.store._disk),
-                                  np.asarray(chunked.store._disk))
-    np.testing.assert_array_equal(whole.store._abs_km, chunked.store._abs_km)
-    np.testing.assert_array_equal(whole.store._abs_kn, chunked.store._abs_kn)
+    for a, b in ((whole.store._disk, chunked.store._disk),
+                 (whole.store._abs_km, chunked.store._abs_km),
+                 (whole.store._abs_kn, chunked.store._abs_kn)):
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32),
+                                   rtol=2.0 ** -10, atol=2.0 ** -20)
     assert (list(whole.store.tier[0].reshape(-1))
             == list(chunked.store.tier[0].reshape(-1)))
     whole.store.close()

@@ -19,7 +19,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import get_config, get_shape
 from repro.configs.base import ShapeCfg
 from repro.launch import steps as stp
-from repro.launch.mesh import make_host_mesh, set_mesh
+from repro.launch.mesh import make_host_mesh
 from repro.models import lm
 from repro.optim import adamw
 
@@ -38,7 +38,7 @@ step1 = jax.jit(stp.make_train_step(cfg, tcfg))
 s1, m1 = step1(jax.tree.map(jnp.copy, state), batch)
 
 # distributed
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     shape = ShapeCfg("t", S, B, "train")
     jitted, ss, bspec = stp.make_jitted_train_step(cfg, mesh, tcfg, shape)
     # deep-copy before device_put: the jitted step donates its state arg and
@@ -69,7 +69,7 @@ prompt = jnp.asarray(rng.randint(1, cfg.vocab_size, (8, 96)), jnp.int32)
 _, cache_local = lm.prefill(params, cfg_d, {"tokens": prompt}, max_len=128)
 logits_local, _ = lm.decode_step(params, cfg_d, cache_local,
                                  {"token": prompt[:, -1]}, jnp.int32(96))
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     jd = stp.make_jitted_decode(cfg_d, mesh, shape_d)
     csh = jax.tree.map(lambda p: NamedSharding(mesh, p),
                        stp.cache_specs(cfg_d, mesh, shape_d),
