@@ -1,0 +1,201 @@
+"""The harness driven end to end on the CPU at the small variant's size,
+with the chip check and the persistent compile cache switched off here
+(in the test only), and the correctness check shown to fail for the
+faults it has to catch."""
+import json
+import os
+import shutil
+import subprocess
+import sys
+from pathlib import Path
+
+import numpy as np
+import pytest
+
+import cell
+import gen_traffic
+import run
+
+ROOT = Path(__file__).resolve().parents[2]
+
+# The small variant computes in float32, so its served tokens read a gap
+# of about 0 against the reference at the served selection budget
+# (readings on the CPU: 0.0, 0.0 and 0.0046 on three seeds: the choice of
+# chunks agrees with the reference's to rounding); the float8 control
+# reads 0.31-0.39 and an altered token a large share of the logits'
+# spread.  These limits sit between them for the small variant only; the
+# cells' own limits are in bench/limits/.
+SMOKE_LIMIT = 0.02
+SMOKE_LIMITS = {"logit_gap": SMOKE_LIMIT, "median_gap": SMOKE_LIMIT}
+
+
+def _mix(workload, **kw):
+    mix = dict(gen_traffic.load(cell.workload(workload)["traffic"]))
+    mix.update(prompt={"law": "uniform", "lo": 150, "hi": 200},
+               max_len=256, clients=2, check_sample=2, warm_rounds=2)
+    mix.update(kw)
+    return mix
+
+
+@pytest.fixture(autouse=True)
+def _harness_for_tests(monkeypatch):
+    monkeypatch.setattr(run, "configure_jax", lambda: None)
+
+
+def _run(workload, seed, trace=False, seconds=1.5, mix=None, **kw):
+    return run.run(workload, seed, seconds, trace, smoke=True,
+                   mix=mix or _mix(workload), need_chip=False,
+                   limits=SMOKE_LIMITS, **kw)
+
+
+def test_smoke_run_prints_the_contract_keys():
+    res = _run("phi4-decode-8k", 2**33 + 5)
+    assert list(res)[:5] == ["correct", "attempted", "failed", "metrics",
+                             "device"]
+    assert list(res)[-1] == "checks"
+    assert res["correct"] is True
+    assert set(res["metrics"]) == {"decode_tok_s", "itl_p90_ms", "setup_s"}
+    assert all(m["value"] > 0 for m in res["metrics"].values())
+    assert res["checks"]["logit_gap"]["value"] <= SMOKE_LIMIT
+    assert res["checks"]["tokens_compared"]["value"] > 0
+    json.dumps(res)
+
+
+def test_traced_smoke_run_reads_the_per_layer_metrics():
+    res = _run("phi4-decode-8k", 77, trace=True)
+    names = set(res["metrics"])
+    # on the CPU the trace has no TPU plane, so the device metrics are
+    # left out; the rest of the cell's per-layer metrics are read
+    assert {"batch_occupancy", "round_select_ms", "round_fetch_ms",
+            "pool_hit_rate", "disk_read_mb_per_round", "decode_mfu"} <= names
+    # the pool holds one round's worst case, not every chunk: the tier
+    # store misses and reads from the disk
+    assert res["metrics"]["pool_hit_rate"]["value"] < 1.0
+    assert res["metrics"]["disk_read_mb_per_round"]["value"] > 0
+    assert not names & {"decode_tok_s", "setup_s"}
+    assert res["device"]["window_s"] > 0
+    assert set(res["breakdown"]) == {"device_ops", "idle_gaps"}
+    assert res["correct"] is True
+
+
+def test_a_serving_mix_runs_through_the_same_harness():
+    """A mix of short requests that finish in the window (``measure``
+    "serve"): admissions run inside it, and finished requests are
+    compared."""
+    mix = _mix("phi4-decode-8k", measure="serve", max_new=4,
+               prompt={"law": "zipf_geometric", "a": 1.4, "rank_cap": 64,
+                       "lo": 40, "hi": 240},
+               check_sample=3, lead_in_requests=2, set_size=8)
+    res = run.run("phi4-decode-8k", 5, 1.5, False, smoke=True, mix=mix,
+                  need_chip=False, limits=SMOKE_LIMITS)
+    assert res["correct"] is True
+    assert res["attempted"] >= 2
+    assert res["checks"]["tokens_compared"]["value"] >= 3 * 4
+
+
+def test_an_altered_token_fails_the_check(monkeypatch):
+    from repro.serving.engine import BatchedLeoAMEngine
+    orig = BatchedLeoAMEngine.decode_round
+    calls = {"n": 0}
+
+    def altered(self, tokens):
+        out = orig(self, tokens)
+        calls["n"] += 1
+        if calls["n"] == 3:                 # one token, where it is made
+            sid = min(out)
+            out[sid] = (out[sid] + 1) % self.cfg.vocab_size
+        return out
+
+    monkeypatch.setattr(BatchedLeoAMEngine, "decode_round", altered)
+    res = _run("phi4-decode-8k", 11)
+    assert calls["n"] >= 3
+    assert res["correct"] is False
+    assert res["checks"]["logit_gap"]["value"] > SMOKE_LIMIT
+
+
+@pytest.mark.parametrize("seed", [2**31 + 9, 4])
+def test_the_fp8_control_fails_the_check(seed):
+    """The control, the reference in float8, put in the program's place
+    through the harness's own check."""
+    # a fixed number of tokens from the warm-up, whatever the CPU's speed
+    res = _run("phi4-decode-8k", seed, control="fp8", seconds=0.3,
+               mix=_mix("phi4-decode-8k", warm_rounds=10))
+    assert res["correct"] is False
+    assert res["checks"]["logit_gap"]["value"] > SMOKE_LIMIT
+    assert res["readings"]["program"]["logit_gap"] <= SMOKE_LIMIT
+    assert res["checks"]["logit_gap"]["value"] == \
+        res["readings"]["fp8"]["logit_gap"]
+
+
+def test_the_command_takes_the_control(monkeypatch, capsys):
+    seen = {}
+
+    def fake(workload, seed, seconds, trace, control=None):
+        seen.update(workload=workload, seed=seed, control=control)
+        return {"correct": False, "checks": {"logit_gap": {"value": 1.0,
+                                                           "limit": 0.5}}}
+
+    monkeypatch.setattr(run, "run", fake)
+    assert run.main(["--workload", "phi4-decode-8k", "--seed", str(2**40),
+                     "--seconds", "1", "--control", "fp8"]) == 0
+    assert seen == {"workload": "phi4-decode-8k", "seed": 2**40,
+                    "control": "fp8"}
+    out = capsys.readouterr()
+    assert json.loads(out.out.splitlines()[-1])["correct"] is False
+    assert out.err.splitlines()[-1] == "check logit_gap = 1.0 (limit 0.5)"
+
+
+@pytest.mark.parametrize("seed", range(3))
+def test_the_reference_chooses_chunks_as_the_program_does(seed):
+    """The reference's own copy of the branch-and-bound choice against the
+    program's, on scores with ties and a short last chunk."""
+    from repro.core.adaptive import tree_select_chunks
+    import reference
+    rng = np.random.default_rng(seed)
+    chunk = 64
+    for _ in range(200):
+        length = int(rng.integers(1, 40 * chunk))
+        nc = -(-length // chunk)
+        scores = rng.integers(0, 6, nc).astype(np.float64) / chunk
+        budget = max(chunk, int(np.ceil(length * rng.choice([0.1, 0.5]))))
+        want, _ = tree_select_chunks(scores, length, budget, chunk)
+        assert reference.tree_select(scores, length, budget, chunk) == want
+
+
+@pytest.mark.parametrize("gaps, want", [
+    ([0.0] * 9 + [1.5], {"logit_gap": 1.5, "median_gap": 0.0}),
+    ([0.0, 0.2, 0.3, 0.0, 0.4], {"logit_gap": 0.4, "median_gap": 0.2}),
+])
+def test_the_numbers_compared(gaps, want):
+    """The widest gap is set by one token; the median by most of them."""
+    import check
+    assert check.numbers(gaps) == pytest.approx(want)
+
+
+def test_the_pool_holds_one_rounds_worst_case():
+    conf = cell.config("phi4-mini-3.8b")
+    mix = gen_traffic.load("decode-8k")
+    # 8192 tokens at the early layers' 50%: 64 chunks, one more where the
+    # budget splits one, sink 1, recent 2, hot 6 (5% of 128): 74 a session
+    assert cell.pool_chunks(conf, mix) == 74 * int(mix["clients"])
+
+
+def test_the_chip_check_refuses_the_cpu():
+    with pytest.raises(SystemExit):
+        run.device_check(1)
+
+
+def test_no_result_without_the_program(tmp_path):
+    """In a directory holding only BENCHMARK.json and bench/, the command
+    exits non-zero and prints no result."""
+    shutil.copy(ROOT / "BENCHMARK.json", tmp_path / "BENCHMARK.json")
+    shutil.copytree(ROOT / "bench", tmp_path / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("PYTHONPATH", None)
+    p = subprocess.run([sys.executable, "bench/run.py", "--workload",
+                        "phi4-decode-8k", "--seed", "1", "--seconds", "1",
+                        "--trace", "0"], cwd=tmp_path, env=env,
+                       capture_output=True, text=True, timeout=300)
+    assert p.returncode != 0
+    assert '"correct"' not in p.stdout
