@@ -110,6 +110,7 @@ from repro.models import attention as attn_mod
 from repro.serving.faults import AdmissionError, ChunkLostError
 from repro.serving.offload import DEVICE, DISK, HOST, TieredKVStore
 from repro.serving.sanitizer import decode_thread_only, worker_thread
+from repro.serving.tracing import Round, span
 
 
 @dataclass
@@ -1051,72 +1052,78 @@ class BatchedLeoAMEngine:
                    for sid, L in zip(order, lengths)}
         chunks_by_seq = {sid: list(range(n_valid[sid])) for sid in order}
         use_pq = self.ecfg.pq_abstracts
-        fut = self._pf_futs.pop(li, None)
-        if fut is not None:
-            fut.result()
-        cached = self._abs_cache.pop(li, None)
-        key = tuple((sid, n_valid[sid]) for sid in order)
-        if cached is not None and cached[0] == key:
-            res = cached[1]
-        else:   # speculation miss (round composition changed): sync read.
-                # The worker's read stays billed — two reads really
-                # happened; that is the cost of a wrong speculation.
-            res = (self.store.read_abstracts_pq_batch(li, chunks_by_seq)
-                   if use_pq
-                   else self.store.read_abstracts_batch(li, chunks_by_seq))
-        if use_pq:
-            km, kn, pq_codes, pq_valid, pq_cb, abs_billed = res
-        else:
-            km, kn, abs_billed = res
-            pq_valid = None
-
-        qj = jnp.asarray(q)                                  # (B, H, d)
-        ub, _ = chunk_bounds_gqa_matmul(qj, jnp.asarray(km), jnp.asarray(kn))
-        ub = np.asarray(ub)                                  # (B, Hkv, ncmax)
-        adc = None
-        if use_pq and pq_valid.any():
-            # asymmetric-distance scores off the PQ codes: the exact-logit
-            # analog of the bounds path's group sum — q summed per kv
-            # group against decoded centroids, max over a chunk's live
-            # tokens.  Only code-valid chunks use it; the rest keep the
-            # min/max upper bound BITWISE (np.where below selects whole
-            # values, never mixes them).
-            B, H = q.shape[0], q.shape[1]
-            Hkv = km.shape[2]
-            q_sum = q.reshape(B, Hkv, H // Hkv, -1).sum(2)   # (B, Hkv, d)
-            adc = adc_chunk_scores(q_sum, pq_cb, pq_codes,
-                                   np.asarray(lengths))      # (B, Hkv, nc)
-
-        rate = (cfg.leoam.early_rate if layer < cfg.leoam.early_layers
-                else cfg.leoam.importance_rate)
-        sels: Dict[int, List[int]] = {}
-        stats: Dict[int, StepStats] = {}
-        for i, sid in enumerate(order):
-            st = StepStats(abstract_bytes=abs_billed[sid])
-            nv = n_valid[sid]
-            length = int(lengths[i])
-            scores = ub[i].max(0)[:nv]                       # (nv,)
-            if adc is not None:
-                v = pq_valid[i, :nv]
-                scores = np.where(v, adc[i].max(0)[:nv], scores)
-            budget_tokens = max(chunk, int(math.ceil(length * rate)))
-            # chunk-level fast path: equivalent to the per-token
-            # repeat+select (tested) without the length-S allocation
-            chunk_scores = scores / chunk
-            if self.ecfg.selection == "tree":
-                sel, st.evaluations = tree_select_chunks(
-                    chunk_scores, length, budget_tokens, chunk)
+        with span("leoam.select.abstracts"):
+            fut = self._pf_futs.pop(li, None)
+            if fut is not None:
+                fut.result()
+            cached = self._abs_cache.pop(li, None)
+            key = tuple((sid, n_valid[sid]) for sid in order)
+            if cached is not None and cached[0] == key:
+                res = cached[1]
+            else:   # speculation miss (round composition changed): sync
+                    # read.  The worker's read stays billed — two reads
+                    # really happened; that is the cost of a wrong
+                    # speculation.
+                res = (self.store.read_abstracts_pq_batch(li, chunks_by_seq)
+                       if use_pq
+                       else self.store.read_abstracts_batch(li,
+                                                            chunks_by_seq))
+            if use_pq:
+                km, kn, pq_codes, pq_valid, pq_cb, abs_billed = res
             else:
-                sel, st.evaluations = flat_select_chunks(
-                    chunk_scores, length, budget_tokens, chunk)
-            # sink + recent + hot chunks always included
-            forced = set(range(cfg.leoam.sink_chunks))
-            forced.update(range(max(0, nv - cfg.leoam.recent_chunks), nv))
-            forced.update(
-                int(c) for c in self.seqs[sid].access.hot_tokens(
-                    self.ecfg.hot_frac) if c < nv)
-            sels[sid] = sorted(set(sel) | forced)
-            stats[sid] = st
+                km, kn, abs_billed = res
+                pq_valid = None
+            qj = jnp.asarray(q)                              # (B, H, d)
+            kmj, knj = jnp.asarray(km), jnp.asarray(kn)
+
+        with span("leoam.select.bounds"):
+            ub, _ = chunk_bounds_gqa_matmul(qj, kmj, knj)
+            with span("leoam.sync"):
+                ub = np.asarray(ub)                          # (B, Hkv, ncmax)
+            adc = None
+            if use_pq and pq_valid.any():
+                # asymmetric-distance scores off the PQ codes: the
+                # exact-logit analog of the bounds path's group sum — q
+                # summed per kv group against decoded centroids, max over
+                # a chunk's live tokens.  Only code-valid chunks use it;
+                # the rest keep the min/max upper bound BITWISE (np.where
+                # below selects whole values, never mixes them).
+                B, H = q.shape[0], q.shape[1]
+                Hkv = km.shape[2]
+                q_sum = q.reshape(B, Hkv, H // Hkv, -1).sum(2)  # (B, Hkv, d)
+                adc = adc_chunk_scores(q_sum, pq_cb, pq_codes,
+                                       np.asarray(lengths))  # (B, Hkv, nc)
+        with span("leoam.select.choose"):
+            rate = (cfg.leoam.early_rate if layer < cfg.leoam.early_layers
+                    else cfg.leoam.importance_rate)
+            sels: Dict[int, List[int]] = {}
+            stats: Dict[int, StepStats] = {}
+            for i, sid in enumerate(order):
+                st = StepStats(abstract_bytes=abs_billed[sid])
+                nv = n_valid[sid]
+                length = int(lengths[i])
+                scores = ub[i].max(0)[:nv]                       # (nv,)
+                if adc is not None:
+                    v = pq_valid[i, :nv]
+                    scores = np.where(v, adc[i].max(0)[:nv], scores)
+                budget_tokens = max(chunk, int(math.ceil(length * rate)))
+                # chunk-level fast path: equivalent to the per-token
+                # repeat+select (tested) without the length-S allocation
+                chunk_scores = scores / chunk
+                if self.ecfg.selection == "tree":
+                    sel, st.evaluations = tree_select_chunks(
+                        chunk_scores, length, budget_tokens, chunk)
+                else:
+                    sel, st.evaluations = flat_select_chunks(
+                        chunk_scores, length, budget_tokens, chunk)
+                # sink + recent + hot chunks always included
+                forced = set(range(cfg.leoam.sink_chunks))
+                forced.update(range(max(0, nv - cfg.leoam.recent_chunks), nv))
+                forced.update(
+                    int(c) for c in self.seqs[sid].access.hot_tokens(
+                        self.ecfg.hot_frac) if c < nv)
+                sels[sid] = sorted(set(sel) | forced)
+                stats[sid] = st
         return sels, stats
 
     # ------------------------------------------------------------------
@@ -1156,26 +1163,36 @@ class BatchedLeoAMEngine:
                 "{seq id: last token} for every live sequence (admit one "
                 "via add_sequence / add_sequence_async first)")
         live = dict(tokens)
-        for sid in sorted(live):        # write-behind completion fence: no
-            try:                        # read sees a half-written replica
-                self.store.ingest_fence(sid)
-            except BaseException as e:
-                self.ingest_errors += 1
-                self.fail_sequence(sid, f"cold ingest failed: {e!r}")
-                live.pop(sid)
-        for _ in range(self._MAX_ROUND_RETRIES):
-            if not live:
-                return {}
-            snap = self._snapshot_round(live)
-            try:
-                return self._decode_round_impl(live)
-            except ChunkLostError as e:
-                self._restore_round(snap)
-                self._recover_lost(e, live)
-        raise RuntimeError(
-            f"decode round failed to converge after "
-            f"{self._MAX_ROUND_RETRIES} chunk-loss recoveries — the disk "
-            f"is losing chunks faster than recompute restores them")
+        n_prof = len(self.round_profiles)
+        with Round() as rnd:
+            with span("leoam.fence"):
+                for sid in sorted(live):    # write-behind completion fence:
+                    try:                    # no read sees a half-written
+                        self.store.ingest_fence(sid)          # replica
+                    except BaseException as e:
+                        self.ingest_errors += 1
+                        self.fail_sequence(sid, f"cold ingest failed: {e!r}")
+                        live.pop(sid)
+            for _ in range(self._MAX_ROUND_RETRIES):
+                if not live:
+                    return {}
+                snap = self._snapshot_round(live)
+                try:
+                    out = self._decode_round_impl(live)
+                    break
+                except ChunkLostError as e:
+                    self._restore_round(snap)
+                    self._recover_lost(e, live)
+            else:
+                raise RuntimeError(
+                    f"decode round failed to converge after "
+                    f"{self._MAX_ROUND_RETRIES} chunk-loss recoveries — the "
+                    f"disk is losing chunks faster than recompute restores "
+                    f"them")
+        # the round's span self times and compiles join its profile (the
+        # phases of a retried round include its failed attempts)
+        self.round_profiles[n_prof].update(rnd.profile())
+        return out
 
     def _snapshot_round(self, live: Dict[int, int]) -> Dict[str, Any]:
         """Capture the host-side state a partial round mutates before its
@@ -1303,31 +1320,38 @@ class BatchedLeoAMEngine:
 
         def run_attn(blk, kind, mlpk, h, layer_idx):
             nonlocal li
-            hln = attn_mod.rms_norm(h, blk["ln1"], cfg.norm_eps)
-            pos = jnp.asarray(lengths[:, None], jnp.int32)   # (B, 1)
-            if self.mla:
-                # absorbed MLA: the query lives in latent space (q_lat =
-                # q_nope @ W_UK ‖ q_rope) and the new token's cache row is
-                # ONE latent vector; both selection and attention run over
-                # the store's single latent plane
-                m = cfg.mla
-                p = blk["core"]
-                q_nope, q_rope = attn_mod._mla_q(p, cfg, hln, pos)
-                scale = 1.0 / math.sqrt(m.qk_nope_head_dim
-                                        + m.qk_rope_head_dim)
-                q_lat = jnp.einsum("bhd,hrd->bhr", q_nope[:, 0],
-                                   p["wk_b"]) * scale
-                q_rope = q_rope[:, 0] * scale
-                kv_a = (hln @ p["wkv_a"])[:, 0]
-                ckv_new = attn_mod.rms_norm(kv_a[:, : m.kv_lora_rank],
-                                            p["kv_norm"], cfg.norm_eps)
-                krope_new = attn_mod.rotate(
-                    cfg, kv_a[:, None, None, m.kv_lora_rank:], pos)[:, 0, 0]
-                lat_new = jnp.concatenate([ckv_new, krope_new], axis=-1)
-                qn = np.asarray(jnp.concatenate([q_lat, q_rope], axis=-1))
-            else:
-                q, k_new, v_new = attn_mod._qkv(blk["core"], cfg, hln, pos)
-                qn = np.asarray(q[:, 0]) / math.sqrt(cfg.hd)  # (B, H, hd)
+            with span("leoam.qkv"):
+                hln = attn_mod.rms_norm(h, blk["ln1"], cfg.norm_eps)
+                pos = jnp.asarray(lengths[:, None], jnp.int32)   # (B, 1)
+                if self.mla:
+                    # absorbed MLA: the query lives in latent space (q_lat
+                    # = q_nope @ W_UK ‖ q_rope) and the new token's cache
+                    # row is ONE latent vector; both selection and
+                    # attention run over the store's single latent plane
+                    m = cfg.mla
+                    p = blk["core"]
+                    q_nope, q_rope = attn_mod._mla_q(p, cfg, hln, pos)
+                    scale = 1.0 / math.sqrt(m.qk_nope_head_dim
+                                            + m.qk_rope_head_dim)
+                    q_lat = jnp.einsum("bhd,hrd->bhr", q_nope[:, 0],
+                                       p["wk_b"]) * scale
+                    q_rope = q_rope[:, 0] * scale
+                    kv_a = (hln @ p["wkv_a"])[:, 0]
+                    ckv_new = attn_mod.rms_norm(kv_a[:, : m.kv_lora_rank],
+                                                p["kv_norm"], cfg.norm_eps)
+                    krope_new = attn_mod.rotate(
+                        cfg, kv_a[:, None, None, m.kv_lora_rank:],
+                        pos)[:, 0, 0]
+                    lat_new = jnp.concatenate([ckv_new, krope_new], axis=-1)
+                    q_sel = jnp.concatenate([q_lat, q_rope], axis=-1)
+                else:
+                    q, k_new, v_new = attn_mod._qkv(blk["core"], cfg, hln,
+                                                    pos)
+                    q_sel = q[:, 0]                          # (B, H, hd)
+            with span("leoam.sync"):
+                qn = np.asarray(q_sel)
+            if not self.mla:
+                qn = qn / math.sqrt(cfg.hd)
             t0 = time.perf_counter()
             sels, sel_stats = self._select_chunks_batched(
                 li, layer_idx, qn, order, lengths)
@@ -1346,8 +1370,9 @@ class BatchedLeoAMEngine:
                 self._prev_sels[(sid, li)] = sels[sid]
 
             if ecfg.pooled:
-                slots, _, fst = self.store.fetch_chunks_pooled(
-                    li, sels, pad_to=nmax, theta=self._theta(li))
+                with span("leoam.fetch"):
+                    slots, _, fst = self.store.fetch_chunks_pooled(
+                        li, sels, pad_to=nmax, theta=self._theta(li))
                 prof["gather_s"] += fst.gather_s
                 prof["upload_s"] += fst.upload_s
                 layer_io.append((li, fst.uploads * self.store.chunk_bytes,
@@ -1355,74 +1380,84 @@ class BatchedLeoAMEngine:
                 for sid in order:
                     round_stats[sid].fetched_bytes += fst.upload_bytes / B
                 # overlap: next layer's reads under this layer's attention
-                self._submit_prefetch(li + 1, order, lengths)
-                chunk_ids = np.full((B, nmax), -1, np.int32)
-                for i, sid in enumerate(order):
-                    chunk_ids[i, :len(sels[sid])] = sels[sid]
-                pool = self.store.pools[li]
-                t1 = time.perf_counter()
-                if self.mla:
-                    y = _attend_pooled_mla(
-                        q_lat, q_rope, pool.kv, jnp.asarray(slots),
-                        jnp.asarray(chunk_ids),
-                        jnp.asarray(lengths.astype(np.int32)),
-                        lat_new, blk["core"]["wv_b"], blk["core"]["wo"])
-                else:
-                    y = _attend_pooled(q, pool.kv, jnp.asarray(slots),
-                                       jnp.asarray(chunk_ids),
-                                       jnp.asarray(lengths.astype(np.int32)),
-                                       k_new, v_new, blk["core"]["wo"],
-                                       attn_softcap=cfg.attn_softcap)
-                if ecfg.profile:
-                    jax.block_until_ready(y)
-                    prof["attend_s"] += time.perf_counter() - t1
+                with span("leoam.prefetch"):
+                    self._submit_prefetch(li + 1, order, lengths)
+                with span("leoam.attend"):
+                    chunk_ids = np.full((B, nmax), -1, np.int32)
+                    for i, sid in enumerate(order):
+                        chunk_ids[i, :len(sels[sid])] = sels[sid]
+                    pool = self.store.pools[li]
+                    t1 = time.perf_counter()
+                    if self.mla:
+                        y = _attend_pooled_mla(
+                            q_lat, q_rope, pool.kv, jnp.asarray(slots),
+                            jnp.asarray(chunk_ids),
+                            jnp.asarray(lengths.astype(np.int32)),
+                            lat_new, blk["core"]["wv_b"], blk["core"]["wo"])
+                    else:
+                        y = _attend_pooled(
+                            q, pool.kv, jnp.asarray(slots),
+                            jnp.asarray(chunk_ids),
+                            jnp.asarray(lengths.astype(np.int32)),
+                            k_new, v_new, blk["core"]["wo"],
+                            attn_softcap=cfg.attn_softcap)
+                    if ecfg.profile:
+                        jax.block_until_ready(y)
+                        prof["attend_s"] += time.perf_counter() - t1
             else:
-                # positions per padded slot; sentinel pads fail pos < len.
-                # Strict mask: the store holds tokens 0..length-1 here
-                # (this round's token rides in k_new/v_new), so pos ==
-                # length is an unwritten/stale row, never attended.
-                S = nmax * self.chunk + 1
-                pos_np = np.full((B, S), np.iinfo(np.int64).max, np.int64)
-                for i, sid in enumerate(order):
-                    sel = np.asarray(sels[sid])
-                    p = (sel[:, None] * self.chunk
-                         + np.arange(self.chunk)[None]).reshape(-1)
-                    pos_np[i, :len(p)] = p
-                valid_np = pos_np < lengths[:, None]
-                valid_np[:, -1] = True           # the new token's column
-                valid = jnp.asarray(valid_np)[:, None, None]
-                t1 = time.perf_counter()
-                kg, vg, _ = self.store.fetch_chunks_batch(li, sels,
-                                                          pad_to=nmax)
-                prof["gather_s"] += time.perf_counter() - t1
-                t1 = time.perf_counter()
-                kgj = jnp.asarray(kg)
-                vgj = kgj if self.mla else jnp.asarray(vg)
-                prof["upload_s"] += time.perf_counter() - t1
-                t1 = time.perf_counter()
+                with span("leoam.fetch"):
+                    t1 = time.perf_counter()
+                    kg, vg, _ = self.store.fetch_chunks_batch(li, sels,
+                                                              pad_to=nmax)
+                    prof["gather_s"] += time.perf_counter() - t1
+                    t1 = time.perf_counter()
+                    kgj = jnp.asarray(kg)
+                    vgj = kgj if self.mla else jnp.asarray(vg)
+                    prof["upload_s"] += time.perf_counter() - t1
+                with span("leoam.attend"):
+                    # positions per padded slot; sentinel pads fail pos <
+                    # len.  Strict mask: the store holds tokens
+                    # 0..length-1 here (this round's token rides in
+                    # k_new/v_new), so pos == length is an unwritten/stale
+                    # row, never attended.
+                    S = nmax * self.chunk + 1
+                    pos_np = np.full((B, S), np.iinfo(np.int64).max,
+                                     np.int64)
+                    for i, sid in enumerate(order):
+                        sel = np.asarray(sels[sid])
+                        p = (sel[:, None] * self.chunk
+                             + np.arange(self.chunk)[None]).reshape(-1)
+                        pos_np[i, :len(p)] = p
+                    valid_np = pos_np < lengths[:, None]
+                    valid_np[:, -1] = True           # the new token's column
+                    valid = jnp.asarray(valid_np)[:, None, None]
+                    t1 = time.perf_counter()
+                    if self.mla:
+                        y = _attend_workingset_mla(
+                            q_lat, q_rope, kgj, lat_new, valid,
+                            blk["core"]["wv_b"], blk["core"]["wo"])
+                    else:
+                        y = _attend_workingset(
+                            q, kgj, vgj, k_new, v_new, valid,
+                            blk["core"]["wo"],
+                            attn_softcap=cfg.attn_softcap)
+                    if ecfg.profile:
+                        jax.block_until_ready(y)
+                        prof["attend_s"] += time.perf_counter() - t1
+            with span("leoam.sync"):
                 if self.mla:
-                    y = _attend_workingset_mla(q_lat, q_rope, kgj, lat_new,
-                                               valid, blk["core"]["wv_b"],
-                                               blk["core"]["wo"])
+                    kn_np = np.asarray(lat_new)[:, None, :]  # (B, 1, D)
+                    vn_np = None
                 else:
-                    y = _attend_workingset(q, kgj, vgj, k_new, v_new, valid,
-                                           blk["core"]["wo"],
-                                           attn_softcap=cfg.attn_softcap)
-                if ecfg.profile:
-                    jax.block_until_ready(y)
-                    prof["attend_s"] += time.perf_counter() - t1
-            if self.mla:
-                lat_np = np.asarray(lat_new)[:, None, :]     # (B, 1, D)
-                self.store.append_tokens_batch(li, lengths, lat_np, None,
-                                               seqs=order)
-            else:
-                kn_np = np.asarray(k_new[:, 0])
-                vn_np = np.asarray(v_new[:, 0])
+                    kn_np = np.asarray(k_new[:, 0])
+                    vn_np = np.asarray(v_new[:, 0])
+            with span("leoam.append"):
                 self.store.append_tokens_batch(li, lengths, kn_np, vn_np,
                                                seqs=order)
             li += 1
-            h = h + y
-            h, _ = lm._apply_mlp(blk, cfg, mlpk, h, None, no_drop=True)
+            with span("leoam.mlp"):
+                h = h + y
+                h, _ = lm._apply_mlp(blk, cfg, mlpk, h, None, no_drop=True)
             return h
 
         def run_other(blk, kind, mlpk, h, layer_idx, cache_slices):
@@ -1441,29 +1476,37 @@ class BatchedLeoAMEngine:
             blk = params["prologue"][pi]
             if kind.startswith("attn"):
                 h = run_attn(blk, kind, mlpk, h, idx)
-            else:
+                continue
+            with span("leoam.recurrent"):
                 slices = [s.cache["prologue"][pi] for s in states]
                 h, new_slices = run_other(blk, kind, mlpk, h, idx, slices)
                 for i in range(B):
                     new_caches[i]["prologue"][pi] = new_slices[i]
         for r in range(repeats):
             for pi, (kind, mlpk) in enumerate(period):
-                blk = jax.tree.map(lambda a: a[r], params["body"][pi])
+                with span("leoam.weights"):
+                    blk = jax.tree.map(lambda a: a[r], params["body"][pi])
                 if kind.startswith("attn"):
                     h = run_attn(blk, kind, mlpk, h, 10 ** 6)
                     continue
-                slices = [jax.tree.map(lambda a: a[r], s.cache["body"][pi])
-                          for s in states]
-                h, new_slices = run_other(blk, kind, mlpk, h, 10 ** 6, slices)
-                for i in range(B):
-                    def put(a, b):
-                        a = np.asarray(a)
-                        a[r] = np.asarray(b)
-                        return a
-                    new_caches[i]["body"][pi] = jax.tree.map(
-                        put, new_caches[i]["body"][pi], new_slices[i])
+                with span("leoam.recurrent"):
+                    slices = [jax.tree.map(lambda a: a[r],
+                                           s.cache["body"][pi])
+                              for s in states]
+                    h, new_slices = run_other(blk, kind, mlpk, h, 10 ** 6,
+                                              slices)
+                    for i in range(B):
+                        def put(a, b):
+                            a = np.asarray(a)
+                            a[r] = np.asarray(b)
+                            return a
+                        new_caches[i]["body"][pi] = jax.tree.map(
+                            put, new_caches[i]["body"][pi], new_slices[i])
 
-        logits = np.asarray(lm._logits(params, cfg, h)[:, 0])  # (B, V)
+        with span("leoam.logits"):
+            logits = lm._logits(params, cfg, h)[:, 0]
+        with span("leoam.sync"):
+            logits = np.asarray(logits)                      # (B, V)
         total_s = time.perf_counter() - t_round
         prof["total_s"] = total_s
         if not ecfg.profile:
@@ -1489,7 +1532,8 @@ class BatchedLeoAMEngine:
             # re-encode of append-dirtied codes (chunks quiet for a full
             # round): long-running sequences regain packed disk->host
             # promotions / ADC scoring instead of fp16/min-max forever
-            self.store.requant_sweep(executor=_prefetch_executor())
+            with span("leoam.requant"):
+                self.store.requant_sweep(executor=_prefetch_executor())
         return out
 
 
