@@ -30,6 +30,11 @@ stages per attention layer:
    is FP-exact: padded keys score -inf, contribute exp(-inf)=0, and adding
    zeros never perturbs the f32 accumulators.
 
+The layer's dense work around the three stages, norm/Q/K/V/rotary before
+and the residual add and MLP after, runs as two compiled programs per
+layer position (:func:`_layer_fn`); a scanned layer's programs take the
+stacked weights and a device repeat index and slice them on device.
+
 With ``pipeline=True`` a one-worker prefetch executor overlaps stage 2 of
 layer l+1 under stage 3 of layer l: while layer l's attention runs, the
 worker reads layer l+1's abstracts and speculatively stages its predicted
@@ -397,6 +402,69 @@ def _attend_workingset_mla(q_lat, q_rope, latg, lat_new, valid, wv_b, wo):
     return _attend_core_mla(q_lat, q_rope, lat, lat_new, valid, wv_b, wo)
 
 
+def _pre_attention(cfg: ArchConfig, blk, h, pos) -> Dict[str, jax.Array]:
+    """One attention layer's dense work before selection: the input norm,
+    the projections (with ``qk_norm`` where the config has it) and rotary
+    at ``pos`` (B, 1).  Returns what selection, attend and append read:
+    GQA ``q`` (B, 1, H, hd), ``q_sel`` = q[:, 0], ``k_new``/``v_new``
+    (B, 1, Hkv, hd); absorbed MLA the pre-scaled ``q_lat`` (B, H, r) and
+    ``q_rope`` (B, H, rr), ``q_sel`` = their concatenation and ``lat_new``
+    (B, D), the token's latent row.  ``wo`` (and MLA's ``wv_b``) come out
+    too, so the attend dispatch takes a scanned layer's weights from here
+    and not from a separate slice."""
+    hln = attn_mod.rms_norm(h, blk["ln1"], cfg.norm_eps)
+    p = blk["core"]
+    if cfg.mla is None:
+        q, k_new, v_new = attn_mod._qkv(p, cfg, hln, pos)
+        return {"q": q, "q_sel": q[:, 0], "k_new": k_new, "v_new": v_new,
+                "wo": p["wo"]}
+    # absorbed MLA: the query lives in latent space (q_lat = q_nope @ W_UK
+    # ‖ q_rope) and the new token's cache row is ONE latent vector; both
+    # selection and attention run over the store's single latent plane
+    m = cfg.mla
+    q_nope, q_rope = attn_mod._mla_q(p, cfg, hln, pos)
+    scale = 1.0 / math.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim)
+    q_lat = jnp.einsum("bhd,hrd->bhr", q_nope[:, 0], p["wk_b"]) * scale
+    q_rope = q_rope[:, 0] * scale
+    kv_a = (hln @ p["wkv_a"])[:, 0]
+    ckv_new = attn_mod.rms_norm(kv_a[:, : m.kv_lora_rank], p["kv_norm"],
+                                cfg.norm_eps)
+    krope_new = attn_mod.rotate(cfg, kv_a[:, None, None, m.kv_lora_rank:],
+                                pos)[:, 0, 0]
+    return {"q_lat": q_lat, "q_rope": q_rope,
+            "q_sel": jnp.concatenate([q_lat, q_rope], axis=-1),
+            "lat_new": jnp.concatenate([ckv_new, krope_new], axis=-1),
+            "wv_b": p["wv_b"], "wo": p["wo"]}
+
+
+def _post_attention(cfg: ArchConfig, mlp_kind: str, blk, h, y) -> jax.Array:
+    """One attention layer's dense work after the attend: the residual add
+    and the MLP (dense or MoE, inference dispatch)."""
+    h, _ = lm._apply_mlp(blk, cfg, mlp_kind, h + y, None, no_drop=True)
+    return h
+
+
+def _layer_fn(cfg: ArchConfig, which: str, stacked: bool, mlp_kind: str):
+    """``(weights, r, h, pos | y)`` -> :func:`_pre_attention` (``which=
+    "pre"``) or :func:`_post_attention` (``"post"``) of one layer.  With
+    ``stacked`` the weights are a body period position's stacked tree and
+    ``r`` a traced int32 repeat index: under jit the slice happens inside
+    the program, where XLA fuses or elides the copy.  Otherwise the
+    weights are a prologue block and ``r`` is None.  The functions' names
+    name the programs in a profiler trace (``jit_pre_attention``,
+    ``jit_post_attention``)."""
+    def pick(w, r):
+        return jax.tree.map(lambda a: a[r], w) if stacked else w
+
+    def pre_attention(w, r, h, pos):
+        return _pre_attention(cfg, pick(w, r), h, pos)
+
+    def post_attention(w, r, h, y):
+        return _post_attention(cfg, mlp_kind, pick(w, r), h, y)
+
+    return pre_attention if which == "pre" else post_attention
+
+
 class BatchedLeoAMEngine:
     """Batched tiered-decoding engine over a decoder-only model.
 
@@ -483,6 +551,13 @@ class BatchedLeoAMEngine:
         self.admit_profiles: List[Dict[str, float]] = []
         self._prefill_cache: Dict[int, Any] = {}
         self._chunk_prefill_cache: Dict[int, Any] = {}
+        # the decode round's compiled dense layer work, keyed by (pre|post,
+        # prologue|body, position); a scanned layer's repeat index rides in
+        # as one of these device scalars, so a program indexes the stacked
+        # weights in place and compiles once per batch size, not per layer
+        self._layer_programs: Dict[Tuple[str, str, int], Any] = {}
+        self._repeat_idx = [jnp.asarray(r, jnp.int32)
+                            for r in range(lm._layer_plan(cfg)[2])]
         self._round_idx = 0
         # fault domain: per-seq terminal failure reasons (scheduler pops
         # them after each round) + engine-level counters
@@ -721,6 +796,17 @@ class BatchedLeoAMEngine:
                 donate_argnums=(2,))
             self._chunk_prefill_cache[C] = fn
         return fn(self.params, batch, cache)
+
+    def _layer_program(self, which: str, where: str, pi: int, mlp_kind: str):
+        """The jitted :func:`_layer_fn` of prologue layer ``pi`` or body
+        period position ``pi`` (``where``), built once per engine; JAX's
+        own cache then keys it on the batch size."""
+        key = (which, where, pi)
+        fn = self._layer_programs.get(key)
+        if fn is None:
+            fn = self._layer_programs[key] = jax.jit(
+                _layer_fn(self.cfg, which, where == "body", mlp_kind))
+        return fn
 
     @decode_thread_only
     def begin_admission(self, tokens: np.ndarray, *,
@@ -1317,39 +1403,17 @@ class BatchedLeoAMEngine:
         li = 0
         new_caches = [{"prologue": list(s.cache["prologue"]),
                        "body": list(s.cache["body"])} for s in states]
+        pos = jnp.asarray(lengths[:, None], jnp.int32)       # (B, 1)
+        lengths_j = jnp.asarray(lengths.astype(np.int32))    # (B,)
 
-        def run_attn(blk, kind, mlpk, h, layer_idx):
+        def run_attn(w, r, where, pi, mlpk, h, layer_idx):
+            """One attention layer; ``w``/``r``/``where``/``pi`` pick its
+            programs' weights (see :meth:`_layer_program`)."""
             nonlocal li
             with span("leoam.qkv"):
-                hln = attn_mod.rms_norm(h, blk["ln1"], cfg.norm_eps)
-                pos = jnp.asarray(lengths[:, None], jnp.int32)   # (B, 1)
-                if self.mla:
-                    # absorbed MLA: the query lives in latent space (q_lat
-                    # = q_nope @ W_UK ‖ q_rope) and the new token's cache
-                    # row is ONE latent vector; both selection and
-                    # attention run over the store's single latent plane
-                    m = cfg.mla
-                    p = blk["core"]
-                    q_nope, q_rope = attn_mod._mla_q(p, cfg, hln, pos)
-                    scale = 1.0 / math.sqrt(m.qk_nope_head_dim
-                                            + m.qk_rope_head_dim)
-                    q_lat = jnp.einsum("bhd,hrd->bhr", q_nope[:, 0],
-                                       p["wk_b"]) * scale
-                    q_rope = q_rope[:, 0] * scale
-                    kv_a = (hln @ p["wkv_a"])[:, 0]
-                    ckv_new = attn_mod.rms_norm(kv_a[:, : m.kv_lora_rank],
-                                                p["kv_norm"], cfg.norm_eps)
-                    krope_new = attn_mod.rotate(
-                        cfg, kv_a[:, None, None, m.kv_lora_rank:],
-                        pos)[:, 0, 0]
-                    lat_new = jnp.concatenate([ckv_new, krope_new], axis=-1)
-                    q_sel = jnp.concatenate([q_lat, q_rope], axis=-1)
-                else:
-                    q, k_new, v_new = attn_mod._qkv(blk["core"], cfg, hln,
-                                                    pos)
-                    q_sel = q[:, 0]                          # (B, H, hd)
+                a = self._layer_program("pre", where, pi, mlpk)(w, r, h, pos)
             with span("leoam.sync"):
-                qn = np.asarray(q_sel)
+                qn = np.asarray(a["q_sel"])
             if not self.mla:
                 qn = qn / math.sqrt(cfg.hd)
             t0 = time.perf_counter()
@@ -1390,16 +1454,14 @@ class BatchedLeoAMEngine:
                     t1 = time.perf_counter()
                     if self.mla:
                         y = _attend_pooled_mla(
-                            q_lat, q_rope, pool.kv, jnp.asarray(slots),
-                            jnp.asarray(chunk_ids),
-                            jnp.asarray(lengths.astype(np.int32)),
-                            lat_new, blk["core"]["wv_b"], blk["core"]["wo"])
+                            a["q_lat"], a["q_rope"], pool.kv,
+                            jnp.asarray(slots), jnp.asarray(chunk_ids),
+                            lengths_j, a["lat_new"], a["wv_b"], a["wo"])
                     else:
                         y = _attend_pooled(
-                            q, pool.kv, jnp.asarray(slots),
-                            jnp.asarray(chunk_ids),
-                            jnp.asarray(lengths.astype(np.int32)),
-                            k_new, v_new, blk["core"]["wo"],
+                            a["q"], pool.kv, jnp.asarray(slots),
+                            jnp.asarray(chunk_ids), lengths_j,
+                            a["k_new"], a["v_new"], a["wo"],
                             attn_softcap=cfg.attn_softcap)
                     if ecfg.profile:
                         jax.block_until_ready(y)
@@ -1434,30 +1496,29 @@ class BatchedLeoAMEngine:
                     t1 = time.perf_counter()
                     if self.mla:
                         y = _attend_workingset_mla(
-                            q_lat, q_rope, kgj, lat_new, valid,
-                            blk["core"]["wv_b"], blk["core"]["wo"])
+                            a["q_lat"], a["q_rope"], kgj, a["lat_new"],
+                            valid, a["wv_b"], a["wo"])
                     else:
                         y = _attend_workingset(
-                            q, kgj, vgj, k_new, v_new, valid,
-                            blk["core"]["wo"],
-                            attn_softcap=cfg.attn_softcap)
+                            a["q"], kgj, vgj, a["k_new"], a["v_new"], valid,
+                            a["wo"], attn_softcap=cfg.attn_softcap)
                     if ecfg.profile:
                         jax.block_until_ready(y)
                         prof["attend_s"] += time.perf_counter() - t1
             with span("leoam.sync"):
+                # the token axis (size 1) drops on the host, not the device
                 if self.mla:
-                    kn_np = np.asarray(lat_new)[:, None, :]  # (B, 1, D)
+                    kn_np = np.asarray(a["lat_new"])[:, None, :]  # (B, 1, D)
                     vn_np = None
                 else:
-                    kn_np = np.asarray(k_new[:, 0])
-                    vn_np = np.asarray(v_new[:, 0])
+                    kn_np = np.asarray(a["k_new"])[:, 0]     # (B, Hkv, hd)
+                    vn_np = np.asarray(a["v_new"])[:, 0]
             with span("leoam.append"):
                 self.store.append_tokens_batch(li, lengths, kn_np, vn_np,
                                                seqs=order)
             li += 1
             with span("leoam.mlp"):
-                h = h + y
-                h, _ = lm._apply_mlp(blk, cfg, mlpk, h, None, no_drop=True)
+                h = self._layer_program("post", where, pi, mlpk)(w, r, h, y)
             return h
 
         def run_other(blk, kind, mlpk, h, layer_idx, cache_slices):
@@ -1475,7 +1536,7 @@ class BatchedLeoAMEngine:
         for pi, (idx, kind, mlpk) in enumerate(prologue):
             blk = params["prologue"][pi]
             if kind.startswith("attn"):
-                h = run_attn(blk, kind, mlpk, h, idx)
+                h = run_attn(blk, None, "prologue", pi, mlpk, h, idx)
                 continue
             with span("leoam.recurrent"):
                 slices = [s.cache["prologue"][pi] for s in states]
@@ -1484,11 +1545,14 @@ class BatchedLeoAMEngine:
                     new_caches[i]["prologue"][pi] = new_slices[i]
         for r in range(repeats):
             for pi, (kind, mlpk) in enumerate(period):
+                if kind.startswith("attn"):
+                    # the programs slice the stacked weights themselves
+                    with span("leoam.weights"):
+                        w, ri = params["body"][pi], self._repeat_idx[r]
+                    h = run_attn(w, ri, "body", pi, mlpk, h, 10 ** 6)
+                    continue
                 with span("leoam.weights"):
                     blk = jax.tree.map(lambda a: a[r], params["body"][pi])
-                if kind.startswith("attn"):
-                    h = run_attn(blk, kind, mlpk, h, 10 ** 6)
-                    continue
                 with span("leoam.recurrent"):
                     slices = [jax.tree.map(lambda a: a[r],
                                            s.cache["body"][pi])

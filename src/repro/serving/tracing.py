@@ -31,8 +31,11 @@ import jax
 DECODE_SPANS = (
     "leoam.round",            # decode_round: fences, retries, round body
     "leoam.fence",            # write-behind ingest fences at round entry
-    "leoam.weights",          # per-round slice of a scanned layer's weights
-    "leoam.qkv",              # norm + query/key/value projection dispatch
+    "leoam.weights",          # picking a layer's weights: a scanned attention
+                              # layer's stacked tree and repeat index (its
+                              # programs slice on device); the slice of a
+                              # scanned recurrent layer's
+    "leoam.qkv",              # pre-attention program: norm, Q/K/V, rotary
     "leoam.sync",             # device -> host reads on the decode thread
     "leoam.select.abstracts",  # chunk abstracts: prefetch wait, read, H2D
     "leoam.select.bounds",    # bounds matmul (and PQ scores) dispatch
@@ -41,7 +44,7 @@ DECODE_SPANS = (
     "leoam.prefetch",         # next layer's speculative prefetch submit
     "leoam.attend",           # chunk ids, their H2D, sparse attend dispatch
     "leoam.append",           # the new token's K/V into the tier store
-    "leoam.mlp",              # residual add + MLP dispatch
+    "leoam.mlp",              # post-attention program: residual add + MLP
     "leoam.recurrent",        # non-attention layers, per sequence
     "leoam.logits",           # final norm + LM head dispatch
     "leoam.requant",          # sidecar / PQ requant sweep at round end

@@ -14,9 +14,11 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs import get_config
 from repro.kernels.kv_quant.kv_quant import kv_dequant_pallas
 from repro.kernels.pq.pq_kmeans import pq_assign_pallas, pq_update_pallas
-from repro.serving.engine import _attend_pooled
+from repro.models import lm
+from repro.serving.engine import _attend_pooled, _layer_fn
 
 # phi4-mini-3.8b: 8 kv heads x head_dim 128, store chunk 64, PQ m = 16
 # subvectors of 8 lanes, K = 256 centroids; N = one layer's keys at
@@ -102,3 +104,35 @@ def test_attend_pooled_compiles_for_v5e(one_chip):
     # the gathered working set is 4 x 16 chunks: scratch stays far below
     # the slab it gathers from
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 28
+
+
+@pytest.mark.parametrize("which", ["pre", "post"])
+def test_layer_programs_compile_for_v5e(one_chip, which):
+    """The decode round's pre- and post-attention programs at
+    phi4-mini-3.8b widths, B=4: the body's stacked weights (30 repeats
+    after the 2 prologue layers: 32 layers) in and a traced layer index,
+    sliced inside the program."""
+    cfg = get_config("phi4-mini-3.8b")
+    _, period, repeats = lm._layer_plan(cfg)
+    assert len(lm._layer_plan(cfg)[0]) + repeats * len(period) == 32
+    B, d = 4, cfg.d_model
+    s = functools.partial(_spec, one_chip)
+    w = jax.tree.map(lambda a: s(a.shape, a.dtype),
+                     lm.abstract_params(cfg)["body"][0])
+    h = s((B, 1, d), jnp.bfloat16)
+    arg = s((B, 1), jnp.int32) if which == "pre" else h
+    lowered = jax.jit(_layer_fn(cfg, which, True, period[0][1])).lower(
+        w, s((), jnp.int32), h, arg)
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    if which == "pre":
+        out = lowered.out_info
+        assert out["q"].shape == (B, 1, cfg.n_heads, cfg.hd)
+        assert out["q_sel"].shape == (B, cfg.n_heads, cfg.hd)
+        assert out["k_new"].shape == (B, 1, cfg.n_kv_heads, cfg.hd)
+        assert out["wo"].shape == w["core"]["wo"].shape[1:]
+    else:
+        assert lowered.out_info.shape == (B, 1, d)
+    # the slice feeds the products in place: no layer's weights are
+    # copied to scratch (one layer's MLP alone is 151 MB)
+    assert mem.temp_size_in_bytes < 2 ** 24
