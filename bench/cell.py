@@ -47,13 +47,24 @@ _RULES_OF_ARCH = ("chunk_size", "importance_rate", "early_layers",
                   "early_rate", "sink_chunks", "recent_chunks")
 
 
+def _replace(obj, overrides: Dict[str, Any]):
+    """``dataclasses.replace`` that descends: a dict given for a field
+    that holds a dataclass replaces fields inside it."""
+    kw = {k: _replace(getattr(obj, k), v)
+          if isinstance(v, dict) and dataclasses.is_dataclass(getattr(obj, k))
+          else v for k, v in overrides.items()}
+    return dataclasses.replace(obj, **kw)
+
+
 def arch(conf: Dict[str, Any], smoke: bool = False):
-    """The program's ArchConfig for a configuration file, with the LeoAM
-    selection rules the file states (``leoam``)."""
+    """The program's ArchConfig for a configuration file, with the
+    ``program.overrides`` it states (nested: ``{"moe": {"n_experts":
+    8}}`` sets one field of the MoE block) and its LeoAM selection rules
+    (``leoam``)."""
     from repro.configs import get_config
     from repro.configs.base import smoke_variant
     prog = conf["program"]
-    cfg = dataclasses.replace(get_config(prog["arch"]), **prog["overrides"])
+    cfg = _replace(get_config(prog["arch"]), prog["overrides"])
     rules = {k: conf["leoam"][k] for k in _RULES_OF_ARCH}
     cfg = dataclasses.replace(cfg, leoam=dataclasses.replace(cfg.leoam,
                                                              **rules))
@@ -69,20 +80,38 @@ def arch(conf: Dict[str, Any], smoke: bool = False):
 
 
 def conf_of_arch(cfg, rules: Dict[str, Any]) -> Dict[str, Any]:
-    """Configuration-file sizes and selection rules of an ArchConfig (for
-    the small variant that tests run, which has no file of its own)."""
+    """Configuration-file sizes and selection rules of an ArchConfig,
+    under the published keys (for the small variant that tests run, which
+    has no file of its own).  Latent attention and experts are reported
+    where the ArchConfig has them; ``norm_topk_prob`` is true because the
+    program's router renormalises its top-k weights."""
     leoam = dict(rules)
     leoam.update({k: getattr(cfg.leoam, k) for k in _RULES_OF_ARCH})
-    return {
+    conf = {
         "hidden_size": cfg.d_model, "num_hidden_layers": cfg.n_layers,
         "num_attention_heads": cfg.n_heads,
         "num_key_value_heads": cfg.n_kv_heads, "head_dim": cfg.hd,
-        "intermediate_size": cfg.d_ff,
+        "intermediate_size": cfg.d_ff_dense or cfg.d_ff,
         "vocab_size": cfg.vocab_size, "rms_norm_eps": cfg.norm_eps,
         "rope_theta": cfg.rope_theta,
         "tie_word_embeddings": cfg.tie_embeddings,
         "leoam": leoam,
     }
+    if cfg.mla is not None:
+        m = cfg.mla
+        conf.update(kv_lora_rank=m.kv_lora_rank, q_lora_rank=m.q_lora_rank,
+                    qk_nope_head_dim=m.qk_nope_head_dim,
+                    qk_rope_head_dim=m.qk_rope_head_dim,
+                    v_head_dim=m.v_head_dim)
+    if cfg.moe is not None:
+        e = cfg.moe
+        conf.update(n_routed_experts=e.n_experts,
+                    num_experts_per_tok=e.top_k,
+                    moe_intermediate_size=e.d_ff_expert,
+                    n_shared_experts=e.n_shared,
+                    first_k_dense_replace=cfg.first_dense,
+                    norm_topk_prob=True, routed_scaling_factor=1.0)
+    return conf
 
 
 def engine_cfg(conf: Dict[str, Any], mix: Dict[str, Any]):

@@ -1,6 +1,6 @@
-"""Engine decode round: the per-round slice of each scanned layer's
-stacked weights (the program's ``leoam.weights`` span, self time) per
-round, in ms."""
+"""Engine decode round: picking each scanned layer's stacked weights and
+repeat index, or a recurrent layer's slice (the program's
+``leoam.weights`` span, self time) per round, in ms."""
 import round_spans
 
 

@@ -7,18 +7,43 @@ weights through ``weights.layer`` (weights the benchmark made from the
 seed), and runs the textbook computation:
 
 * the prompt: a dense causal forward pass (RMSNorm, rotary embedding,
-  grouped-query attention, a SwiGLU MLP, the output head), layer by
-  layer in query blocks, which gives the first served token's logits and
-  each layer's keys and values;
+  attention, the MLP, the output head), layer by layer in query blocks,
+  which gives the first served token's logits and each layer's keys and
+  values;
 * every later token, one step at a time through all layers: the query
-  scores each chunk of ``chunk_size`` cached keys by the upper bound of
-  q.k over the chunk's min/max box, the best chunks are taken up to the
-  layer's token budget (``importance_rate``, ``early_rate`` on the first
-  ``early_layers`` layers) by the branch-and-bound rule of the LeoAM
-  paper, the sink, recent and hot chunks are added, and the token
-  attends to the chosen chunks' keys and to itself.  The hot chunks are
-  the ``hot_frac`` most used ones by a per-sequence use count that
-  decays by ``hot_decay`` at every layer's selection.
+  scores each chunk of ``chunk_size`` cached rows by the upper bound of
+  q.k over the chunk's min/max box (over all the chunk's rows of the
+  zero-initialised cache, as the program's store keeps them), the best
+  chunks are taken up to the layer's token budget (``importance_rate``,
+  ``early_rate`` on the first ``early_layers`` layers) by the
+  branch-and-bound rule of the LeoAM paper, the sink, recent and hot
+  chunks are added, and the token attends to the chosen chunks' keys and
+  to itself.  The hot chunks are the ``hot_frac`` most used ones by a
+  per-sequence use count that decays by ``hot_decay`` at every layer's
+  selection.
+
+The layer kinds follow the published keys the file carries:
+
+* attention is grouped-query, or latent (MLA) where the file has
+  ``kv_lora_rank``: ``q = h.wq`` per head, its rotary part rotated;
+  ``h.wkv_a`` splits into the latent ``c_kv``, RMS-normed by
+  ``kv_norm``, and one rotary key shared by every head; per head
+  ``k = [c_kv.wk_b || k_rope]`` and ``v = c_kv.wv_b``, attention with
+  scale ``1/sqrt(qk_nope_head_dim + qk_rope_head_dim)``, then ``wo``.
+  The cache row the boxes are taken over is the latent row
+  ``[c_kv || k_rope]``, one box per chunk for all heads, and the
+  selection query is the absorbed ``[q_nope.wk_b^T || q_rope]``, scaled,
+  which scores a latent row as q.k scores that row's keys;
+* the MLP is a dense SwiGLU of the weights' width, or on the layers
+  ``flops.moe_layer`` names (``first_k_dense_replace`` on) experts
+  (``_moe``): a float32 softmax router over all the published experts,
+  greedy top-k, the experts held here and the shared ones.
+
+Departures from the published models that the reference shares with the
+program: rotary embedding rotates the first half of the rotated part
+against the second (NeoX), over the whole rotated part, with no
+``rope_scaling`` (no longrope, no yarn); the configuration files list
+these under ``reduced`` or ``assumed``.
 
 ``mode="fp8"`` is the control: the same computation with both operands
 of every product rounded to float8 e4m3 (activations per row, weights
@@ -36,6 +61,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+import flops
 import weights as W
 
 HI = jax.lax.Precision.HIGHEST
@@ -82,28 +108,95 @@ def _sizes(conf):
 
 
 def _qkv(c, h, pos, conf, mode):
-    """Rotated queries and keys, and values, of rows ``h`` at ``pos``."""
+    """Rotated queries and keys, and values, of rows ``h`` at ``pos``, and
+    the cache rows the boxes are taken over: for MLA the latent rows
+    (``_qkv_mla``), for GQA the keys themselves (``None`` comes back)."""
+    if flops.mla(conf):
+        return _qkv_mla(c, h, pos, conf, mode)
     S = h.shape[0]
     H, Hk, hd = _sizes(conf)
     q = _mm(h, c["wq"], mode).reshape(S, H, hd)
     k = _mm(h, c["wk"], mode).reshape(S, Hk, hd)
     v = _mm(h, c["wv"], mode).reshape(S, Hk, hd)
     th = conf["rope_theta"]
-    return _rope(q, pos, th), _rope(k, pos, th), v
+    return _rope(q, pos, th), _rope(k, pos, th), v, None
 
 
-def _mlp(w, x, conf, mode):
-    m = w["mlp"]
+def _qkv_mla(c, h, pos, conf, mode):
+    """Latent attention in its textbook form: per-head queries (S, H,
+    nope + rope), keys [c_kv.wk_b || k_rope] (S, H, nope + rope) and
+    values c_kv.wv_b (S, H, v), and the latent rows [c_kv || k_rope]
+    (S, 1, r + rope)."""
+    if conf.get("q_lora_rank"):
+        raise ValueError("the reference has no low-rank query projection "
+                         "(q_lora_rank)")
+    S = h.shape[0]
+    H, r = conf["num_attention_heads"], conf["kv_lora_rank"]
+    nope, rope = conf["qk_nope_head_dim"], conf["qk_rope_head_dim"]
+    th = conf["rope_theta"]
+    q = _mm(h, c["wq"], mode).reshape(S, H, nope + rope)
+    q = jnp.concatenate([q[..., :nope], _rope(q[..., nope:], pos, th)], -1)
+    kv_a = _mm(h, c["wkv_a"], mode)
+    c_kv = _rms(kv_a[:, :r], c["kv_norm"], conf["rms_norm_eps"])
+    k_rope = _rope(kv_a[:, None, r:], pos, th)               # (S, 1, rope)
+    k_nope = _mm(c_kv, c["wk_b"], mode, "sr,hrd->shd")
+    v = _mm(c_kv, c["wv_b"], mode, "sr,hrd->shd")
+    k = jnp.concatenate([k_nope, jnp.broadcast_to(k_rope, (S, H, rope))], -1)
+    return q, k, v, jnp.concatenate([c_kv, k_rope[:, 0]], -1)[:, None]
+
+
+def _swiglu(m, h, mode, prefix=""):
+    g = _mm(h, m[prefix + "w_gate"], mode)
+    u = _mm(h, m[prefix + "w_up"], mode)
+    return _mm(jax.nn.silu(g) * u, m[prefix + "w_down"], mode)
+
+
+def _moe(m, h, conf, mode):
+    """One expert layer over rows ``h`` (S, d).  The router is a float32
+    softmax over all ``n_routed_experts_published`` experts (where the
+    file does not give that key, over ``n_routed_experts``), greedy top
+    ``num_experts_per_tok``, renormalised only where ``norm_topk_prob``
+    is set, scaled by ``routed_scaling_factor``.  The experts held here
+    are ids 0 .. ``n_routed_experts`` - 1 (chip 0's share of an
+    expert-parallel deployment): each computes its SwiGLU for every row,
+    weighted by the row's routing weight on it, which is 0 where the
+    row did not choose it; the weights on experts held elsewhere add
+    nothing here.  The shared SwiGLU (``n_shared_experts`` x
+    ``moe_intermediate_size`` wide) is added once."""
+    held = conf["n_routed_experts"]
+    published = conf.get("n_routed_experts_published", held)
+    if m["router"].shape[-1] != published or m["w_up"].shape[0] != held:
+        raise ValueError(
+            f"expert weights hold {m['w_up'].shape[0]} experts under a "
+            f"router over {m['router'].shape[-1]}; the configuration "
+            f"states {held} of {published}")
+    probs = jax.nn.softmax(_mm(h, m["router"], mode), axis=-1)
+    top_p, top_e = jax.lax.top_k(probs, conf["num_experts_per_tok"])
+    if conf.get("norm_topk_prob"):
+        top_p = top_p / jnp.sum(top_p, -1, keepdims=True)
+    top_p = top_p * conf.get("routed_scaling_factor", 1.0)
+    gate = jnp.einsum("sk,ske->se", top_p, jax.nn.one_hot(top_e, held),
+                      precision=HI)
+    g = _mm(h, m["w_gate"], mode, "sd,edf->esf")
+    u = _mm(h, m["w_up"], mode, "sd,edf->esf")
+    y = _mm(jax.nn.silu(g) * u, m["w_down"], mode, "esf,efd->esd")
+    out = jnp.einsum("se,esd->sd", gate, y, precision=HI)
+    if conf.get("n_shared_experts"):
+        out = out + _swiglu(m, h, mode, "shared_")
+    return out
+
+
+def _mlp(w, x, conf, mode, moe):
     h = _rms(x, w["ln2"], conf["rms_norm_eps"])
-    g = _mm(h, m["w_gate"], mode)
-    u = _mm(h, m["w_up"], mode)
-    return x + _mm(jax.nn.silu(g) * u, m["w_down"], mode)
+    if moe:
+        return x + _moe(w["mlp"], h, conf, mode)
+    return x + _swiglu(w["mlp"], h, mode)
 
 
 def _causal(q, k, v, scale, mode):
-    """Causal softmax attention.  q: (S, H, d); k, v: (S, Hk, d); query
-    head h reads key head h // (H / Hk).  Query blocks of Q_BLOCK rows
-    each read only the keys up to their last row."""
+    """Causal softmax attention.  q: (S, H, d); k: (S, Hk, d); v: (S, Hk,
+    dv); query head h reads key head h // (H / Hk).  Query blocks of
+    Q_BLOCK rows each read only the keys up to their last row."""
     S, H, _ = q.shape
     G = H // k.shape[1]
     k = jnp.repeat(k, G, axis=1)
@@ -129,39 +222,50 @@ def _causal(q, k, v, scale, mode):
     return jnp.concatenate(outs, axis=0)
 
 
-@functools.partial(jax.jit, static_argnames=("conf_items", "mode"))
-def _prefill_layer(w, x, pos, n, *, conf_items, mode: str):
+@functools.partial(jax.jit, static_argnames=("conf_items", "mode", "moe"))
+def _prefill_layer(w, x, pos, n, *, conf_items, mode: str, moe: bool):
     """One layer over the whole (padded) prompt row; returns the layer's
-    output and its keys and values, zero past the prompt's ``n`` rows."""
+    output, its keys and values and, for MLA, its latent rows, zero past
+    the prompt's ``n`` rows."""
     conf = dict(conf_items)
-    H, _, hd = _sizes(conf)
     c = w["core"]
     h = _rms(x, w["ln1"], conf["rms_norm_eps"])
-    q, k, v = _qkv(c, h, pos, conf, mode)
-    o = _causal(q, k, v, 1.0 / math.sqrt(hd), mode)
-    x = x + _mm(o.reshape(x.shape[0], H * hd), c["wo"], mode)
+    q, k, v, lat = _qkv(c, h, pos, conf, mode)
+    o = _causal(q, k, v, 1.0 / math.sqrt(q.shape[-1]), mode)
+    x = x + _mm(o.reshape(x.shape[0], -1), c["wo"], mode)
     live = (pos < n)[:, None, None]
-    return _mlp(w, x, conf, mode), jnp.where(live, k, 0.0), \
-        jnp.where(live, v, 0.0)
+    if lat is not None:
+        lat = jnp.where(live, lat, 0.0)
+    return _mlp(w, x, conf, mode, moe), jnp.where(live, k, 0.0), \
+        jnp.where(live, v, 0.0), lat
 
 
 @functools.partial(jax.jit, static_argnames=("conf_items", "mode"))
 def _step_qkv(w, x, pos, *, conf_items, mode: str):
-    """One decode token's query, key and value at one layer."""
+    """One decode token's query, key and value at one layer; for MLA also
+    its latent row and the selection query, the absorbed
+    [q_nope.wk_b^T || q_rope] over sqrt(nope + rope)."""
     conf = dict(conf_items)
     h = _rms(x, w["ln1"], conf["rms_norm_eps"])
-    q, k, v = _qkv(w["core"], h, pos[None], conf, mode)
-    return q[0], k[0], v[0]
+    q, k, v, lat = _qkv(w["core"], h, pos[None], conf, mode)
+    if lat is None:
+        return q[0], k[0], v[0], None, None
+    nope = conf["qk_nope_head_dim"]
+    q_lat = _mm(q[0, :, :nope], w["core"]["wk_b"], mode, "hd,hrd->hr")
+    sel = jnp.concatenate([q_lat, q[0, :, nope:]], -1) \
+        * (1.0 / math.sqrt(q.shape[-1]))
+    return q[0], k[0], v[0], lat[0], sel
 
 
-@functools.partial(jax.jit, static_argnames=("conf_items", "mode"),
+@functools.partial(jax.jit, static_argnames=("conf_items", "mode", "moe"),
                    donate_argnums=(5, 6))
-def _step_rest(w, x, q, k, v, kc, vc, pos, mask, *, conf_items, mode: str):
+def _step_rest(w, x, q, k, v, kc, vc, pos, mask, *, conf_items, mode: str,
+               moe: bool):
     """Write the token's key and value at ``pos``, attend to the rows
     ``mask`` marks (the chosen chunks' live rows and the token itself),
     then the output projection and the MLP."""
     conf = dict(conf_items)
-    H, Hk, hd = _sizes(conf)
+    H, Hk, hd = q.shape[0], k.shape[0], q.shape[-1]
     kc = kc.at[pos].set(k)
     vc = vc.at[pos].set(v)
     kk, vv = kc, vc
@@ -173,9 +277,9 @@ def _step_rest(w, x, q, k, v, kc, vc, pos, mask, *, conf_items, mode: str):
     p = jax.nn.softmax(jnp.where(mask[None, None], s, -jnp.inf), axis=-1)
     if mode == "fp8":
         p = _q8(p, -1)
-    o = jnp.einsum("kgt,tkd->kgd", p, vv, precision=HI).reshape(1, H * hd)
+    o = jnp.einsum("kgt,tkd->kgd", p, vv, precision=HI).reshape(1, -1)
     x = x + _mm(o, w["core"]["wo"], mode)
-    return _mlp(w, x, conf, mode), kc, vc
+    return _mlp(w, x, conf, mode, moe), kc, vc
 
 
 @functools.partial(jax.jit, static_argnames=("conf_items", "mode"))
@@ -189,15 +293,21 @@ def _head(params, x, *, conf_items, mode: str):
 
 @functools.partial(jax.jit, static_argnames=("chunk",))
 def _boxes(k, *, chunk: int):
-    """Per-chunk elementwise max and min of the keys (rows past the
-    prompt are zero, as the store's ingested tail chunk is)."""
+    """Per-chunk elementwise max and min of the cache rows.  Rows past
+    the prompt are zero, as the program's cache rows are where no token
+    has been written: the store ingests every chunk up to ``max_len``, so
+    a chunk that decode tokens reach later keeps 0 inside its box."""
     kc = k.reshape(k.shape[0] // chunk, chunk, *k.shape[1:])
     return kc.max(1), kc.min(1)
 
 
 def _conf_items(conf: Dict[str, Any]):
     keep = ("hidden_size", "num_attention_heads", "num_key_value_heads",
-            "head_dim", "rms_norm_eps", "rope_theta", "tie_word_embeddings")
+            "head_dim", "rms_norm_eps", "rope_theta", "tie_word_embeddings",
+            "kv_lora_rank", "q_lora_rank", "qk_nope_head_dim",
+            "qk_rope_head_dim", "v_head_dim", "n_routed_experts",
+            "n_routed_experts_published", "num_experts_per_tok",
+            "norm_topk_prob", "routed_scaling_factor", "n_shared_experts")
     return tuple((k, conf[k]) for k in keep if conf.get(k) is not None)
 
 
@@ -249,8 +359,10 @@ class Chooser:
         self.uses = np.zeros(n_table, np.float64)
 
     def choose(self, layer: int, q: np.ndarray, length: int) -> List[int]:
-        """Chunks layer ``layer``'s query q (H, hd), already scaled by
-        1/sqrt(hd), reads over a cache of ``length`` tokens."""
+        """Chunks layer ``layer``'s selection query q reads over a cache
+        of ``length`` tokens: (H, hd) scaled by 1/sqrt(hd) against the
+        key boxes, or for MLA the absorbed query (H, r + rope) against the
+        latent boxes (one box a chunk for all heads)."""
         r, chunk = self.r, self.chunk
         nv = -(-length // chunk)
         km, kn = self.kmax[layer][:nv], self.kmin[layer][:nv]
@@ -293,26 +405,22 @@ def served_logits(conf: Dict[str, Any], params: Any, prompt: Sequence[int],
     ids[:P] = np.asarray(prompt, np.int64)
     x = jnp.take(params["embed"], jnp.asarray(ids), axis=0).astype(F32)
     pos = jnp.arange(L, dtype=jnp.int32)
+    moe = [flops.moe_layer(conf, i) for i in range(n_layers)]
     kcs, vcs, kmax, kmin = [], [], [], []
     for i in range(n_layers):
-        x, k, v = _prefill_layer(W.layer(params, i), x, pos, P,
-                                 conf_items=items, mode=mode)
+        x, k, v, lat = _prefill_layer(W.layer(params, i), x, pos, P,
+                                      conf_items=items, mode=mode,
+                                      moe=moe[i])
         kcs.append(k)
         vcs.append(v)
-        hi, lo = _boxes(k, chunk=chunk)
-        hi, lo = np.asarray(hi, np.float64), np.asarray(lo, np.float64)
-        # chunks the prompt does not reach hold no box until a token
-        # lands in them
-        hi[-(-P // chunk):] = -np.inf
-        lo[-(-P // chunk):] = np.inf
-        kmax.append(hi)
-        kmin.append(lo)
+        hi, lo = _boxes(k if lat is None else lat, chunk=chunk)
+        kmax.append(np.asarray(hi, np.float64))
+        kmin.append(np.asarray(lo, np.float64))
     out = np.zeros((n, conf["vocab_size"]), np.float32)
     out[0] = np.asarray(_head(params, x[P - 1:P], conf_items=items,
                               mode=mode))[0]
     del x
     chooser = Chooser(rules, max_len // chunk, kmax, kmin)
-    hd = _sizes(conf)[2]
     rows = np.arange(L)
     for t in range(1, n):
         p = P + t - 1
@@ -320,15 +428,18 @@ def served_logits(conf: Dict[str, Any], params: Any, prompt: Sequence[int],
                      axis=0).astype(F32)
         for i in range(n_layers):
             w = W.layer(params, i)
-            q, k, v = _step_qkv(w, x, jnp.int32(p), conf_items=items,
-                                mode=mode)
-            chosen = chooser.choose(i, np.asarray(q) / math.sqrt(hd), p)
+            q, k, v, lat, sel = _step_qkv(w, x, jnp.int32(p),
+                                          conf_items=items, mode=mode)
+            qs = (np.asarray(q) / math.sqrt(q.shape[-1]) if sel is None
+                  else np.asarray(sel))
+            chosen = chooser.choose(i, qs, p)
             pick = np.zeros(L // chunk, bool)
             pick[chosen] = True
             mask = (np.repeat(pick, chunk) & (rows < p)) | (rows == p)
             x, kcs[i], vcs[i] = _step_rest(
                 w, x, q, k, v, kcs[i], vcs[i], jnp.int32(p),
-                jnp.asarray(mask), conf_items=items, mode=mode)
-            chooser.append(i, p, np.asarray(k, np.float64))
+                jnp.asarray(mask), conf_items=items, mode=mode, moe=moe[i])
+            chooser.append(i, p, np.asarray(k if lat is None else lat,
+                                            np.float64))
         out[t] = np.asarray(_head(params, x, conf_items=items, mode=mode))[0]
     return out

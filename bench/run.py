@@ -168,11 +168,15 @@ def warm_buckets(engine, lengths: List[int]) -> List[int]:
 def run(workload: str, seed: int, seconds: float, trace: bool, *,
         control: Optional[str] = None, smoke: bool = False,
         mix: Optional[Dict[str, Any]] = None, need_chip: bool = True,
-        limits: Optional[Dict[str, float]] = None) -> Dict[str, Any]:
+        limits: Optional[Dict[str, float]] = None,
+        rounds: Optional[int] = None) -> Dict[str, Any]:
     """One run of a cell; returns the result object.  With ``control``
     (``"fp8"``) the check compares the control's tokens in the program's
-    place.  ``smoke``, ``mix``, ``need_chip`` and ``limits`` let tests
-    drive the same code at a small size on the CPU."""
+    place.  ``smoke``, ``mix``, ``need_chip``, ``limits`` and ``rounds``
+    let tests drive the same code at a small size on the CPU; with
+    ``rounds`` the window closes after that many decode rounds instead of
+    after ``seconds``, so that what a test serves does not depend on the
+    speed of the CPU."""
     bench = cell.benchmark()
     w = cell.workload(workload)
     conf = cell.config(w["config"])
@@ -254,11 +258,15 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     compiles0 = compiles()
     if probe is not None:
         probe.recording = True
+    last_round = None if rounds is None else loop.decode_rounds + rounds
     with jax.profiler.TraceAnnotation("window"):
         t0 = time.perf_counter()
         while True:
             loop.step()
-            if time.perf_counter() - t0 >= seconds:
+            if last_round is None:
+                if time.perf_counter() - t0 >= seconds:
+                    break
+            elif loop.decode_rounds >= last_round:
                 break
         t1 = time.perf_counter()
     if probe is not None:

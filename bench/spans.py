@@ -7,8 +7,9 @@ named after the layer: ``admit`` (engine admission: prefill and ingest),
 ``decode_round`` (one decode round), ``select`` (chunk selection of one
 layer) and ``fetch`` (tier fetch of one layer).  It also counts, inside
 the window, the chunks each selection picks and the shape of every call
-of the served attend (``_attend_pooled``), so
-that the attend's operations and bytes can be computed from its shapes.
+of the served attend (``_attend_pooled``, or ``_attend_pooled_mla`` for
+latent attention), so that the attend's operations and bytes can be
+computed from its shapes.
 """
 
 from __future__ import annotations
@@ -19,7 +20,8 @@ from typing import Any, Callable, List, Tuple
 import jax
 
 SPAN_NAMES = ("admit", "decode_round", "select", "fetch")
-ATTEND_FN = "_attend_pooled"
+# the served attends, each with the position of its (B, nmax) slots
+ATTEND_FNS = {"_attend_pooled": 2, "_attend_pooled_mla": 3}
 
 
 class Probe:
@@ -55,16 +57,19 @@ class Probe:
         self._wrap(engine, "_select_chunks_batched", "select",
                    self._count_selection)
         self._wrap(engine.store, "fetch_chunks_pooled", "fetch")
-        orig = getattr(eng_mod, ATTEND_FN)
+        for name, slots in ATTEND_FNS.items():
+            self._count_attend(eng_mod, name, slots)
+
+    def _count_attend(self, mod: Any, name: str, slots: int) -> None:
+        orig = getattr(mod, name)
 
         def attend(*a, **k):
             if self.recording:
-                self.attend_calls.append(tuple(a[2].shape))   # slots
+                self.attend_calls.append(tuple(a[slots].shape))
             return orig(*a, **k)
 
-        setattr(eng_mod, ATTEND_FN, attend)
-        self._undo.append(functools.partial(setattr, eng_mod, ATTEND_FN,
-                                            orig))
+        setattr(mod, name, attend)
+        self._undo.append(functools.partial(setattr, mod, name, orig))
 
     def uninstall(self) -> None:
         for undo in reversed(self._undo):

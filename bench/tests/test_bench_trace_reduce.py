@@ -68,3 +68,54 @@ def test_a_trace_recorded_on_the_cpu(tmp_path):
     assert gaps["select"] == pytest.approx(0.05, rel=0.5)
     assert gaps.get("no span", 0.0) >= 0.015
     assert {n for n, _, _ in t.spans} == {"decode_round", "select"}
+
+
+def _idle_gaps_by_scan(t, n=10):
+    """The reduction as a plain scan: every gap cut at every span edge,
+    each piece credited to the shortest span covering its midpoint."""
+    from collections import defaultdict
+    busy = tr.union(tr.clip(t.ops[min(t.ops)], t.window))
+    lo, hi = t.window
+    gaps, at = [], lo
+    for s, e in busy:
+        if s > at:
+            gaps.append((at, s))
+        at = max(at, e)
+    if hi > at:
+        gaps.append((at, hi))
+    spans = tr.clip(t.spans, t.window)
+    edges = sorted({x for _, s, e in spans for x in (s, e)})
+    acc = defaultdict(float)
+    for g0, g1 in gaps:
+        cuts = [g0] + [x for x in edges if g0 < x < g1] + [g1]
+        for a, b in zip(cuts, cuts[1:]):
+            mid = 0.5 * (a + b)
+            cover = [(e - s, nm) for nm, s, e in spans if s <= mid < e]
+            acc[min(cover)[1] if cover else "no span"] += (b - a) * 1e-9
+    return sorted(acc.items(), key=lambda kv: -kv[1])[:n]
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_idle_gaps_equal_the_plain_scan(seed):
+    """Nested rounds of spans (some sharing a length, some cut by the
+    window) over scattered ops: the sweep credits every piece as the
+    scan does, to the last bit."""
+    import random
+    rng = random.Random(seed)
+    ops, spans, t = [], [], 0
+    for _ in range(60):
+        r0 = t
+        for name in ("select", "fetch", "leoam.sync", "leoam.attend"):
+            s = t + rng.randint(0, 40)
+            e = s + rng.choice([25, 50, rng.randint(1, 90)])
+            spans.append((name, s, e))
+            t = e
+        spans.append(("decode_round", r0, t + rng.randint(0, 30)))
+        t += 40
+    for _ in range(400):
+        s = rng.randint(0, t)
+        ops.append(("x", s, s + rng.randint(1, 30)))
+    rng.shuffle(spans)
+    t_ = _trace(ops, spans, (rng.randint(0, 200), t - rng.randint(0, 200)))
+    assert tr.idle_gaps(t_) == _idle_gaps_by_scan(t_)
+    assert tr.idle_gaps(t_, n=3) == _idle_gaps_by_scan(t_, n=3)

@@ -11,7 +11,9 @@ recorded on the CPU, whose op events come from the CPU client's threads.
 
 from __future__ import annotations
 
+import bisect
 import glob
+import heapq
 import os
 from collections import defaultdict
 from dataclasses import dataclass, field
@@ -168,15 +170,26 @@ def idle_gaps(tr: Trace, n: int = 10) -> List[Tuple[str, float]]:
         gaps.append((t, hi))
     spans = sorted(clip(tr.spans, tr.window), key=lambda ev: ev[1])
     # cut each gap at every span edge, credit each piece to the shortest
-    # (innermost) span covering it
+    # (innermost) span covering it, by (length, name).  One sweep: the
+    # pieces' midpoints only grow, so spans open in start order into a
+    # heap and leave it once they have ended.
     acc: Dict[str, float] = defaultdict(float)
     edges = sorted({x for _, s, e in spans for x in (s, e)})
+    open_: List[Tuple[float, str, float]] = []      # (length, name, end)
+    nxt = 0
     for g0, g1 in gaps:
-        cuts = [g0] + [x for x in edges if g0 < x < g1] + [g1]
+        inner = edges[bisect.bisect_right(edges, g0):
+                      bisect.bisect_left(edges, g1)]
+        cuts = [g0] + inner + [g1]
         for a, b in zip(cuts, cuts[1:]):
             mid = 0.5 * (a + b)
-            cover = [(e - s, nm) for nm, s, e in spans if s <= mid < e]
-            acc[min(cover)[1] if cover else "no span"] += (b - a) * 1e-9
+            while nxt < len(spans) and spans[nxt][1] <= mid:
+                nm, s, e = spans[nxt]
+                heapq.heappush(open_, (e - s, nm, e))
+                nxt += 1
+            while open_ and open_[0][2] <= mid:
+                heapq.heappop(open_)
+            acc[open_[0][1] if open_ else "no span"] += (b - a) * 1e-9
     return sorted(acc.items(), key=lambda kv: -kv[1])[:n]
 
 
