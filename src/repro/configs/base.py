@@ -20,15 +20,34 @@ from typing import Optional, Tuple
 
 @dataclass(frozen=True)
 class MoECfg:
-    """Mixture-of-experts block config (GShard-style dense dispatch)."""
+    """Mixture-of-experts block config (GShard-style dense dispatch).
 
-    n_experts: int
+    A layer may hold one share of an expert-parallel deployment: its
+    router scores all ``router_width`` experts of the published model and
+    it holds the weights of ``n_experts`` of them, ids ``first_expert``
+    .. ``first_expert + n_experts - 1``.  By default it holds them all."""
+
+    n_experts: int                 # experts whose weights this layer holds
     top_k: int
     d_ff_expert: int
     n_shared: int = 0              # shared (always-on) experts
     capacity_factor: float = 1.25
     aux_loss_weight: float = 1e-2
     router_z_weight: float = 1e-3
+    router_width: Optional[int] = None  # experts routed over (None: n_experts)
+    first_expert: int = 0          # id of the first expert held here
+    norm_topk: bool = True         # renormalise the top-k routing weights
+
+    def __post_init__(self) -> None:
+        if not 0 <= self.first_expert <= self.n_routed - self.n_experts:
+            raise ValueError(
+                f"experts {self.first_expert}..{self.first_expert + self.n_experts - 1} "
+                f"are not among the {self.n_routed} the router scores")
+
+    @property
+    def n_routed(self) -> int:
+        """Experts the router scores (the published count)."""
+        return self.n_experts if self.router_width is None else self.router_width
 
 
 @dataclass(frozen=True)
@@ -213,7 +232,7 @@ class ArchConfig:
                 assert self.moe is not None
                 m = self.moe
                 per_e = self._ffn_params(m.d_ff_expert)
-                total += (m.top_k + m.n_shared) * per_e + d * m.n_experts
+                total += (m.top_k + m.n_shared) * per_e + d * m.n_routed
             else:
                 total += self._mlp_params(mlp)
             total += 2 * d
@@ -238,7 +257,7 @@ class ArchConfig:
             assert self.moe is not None
             m = self.moe
             per_e = self._ffn_params(m.d_ff_expert)
-            return m.n_experts * per_e + m.n_shared * per_e + self.d_model * m.n_experts
+            return m.n_experts * per_e + m.n_shared * per_e + self.d_model * m.n_routed
         ff = self.d_ff_dense if (mlp_kind == "dense" and self.d_ff_dense) else self.d_ff
         return self._ffn_params(ff)
 

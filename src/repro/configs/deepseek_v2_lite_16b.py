@@ -9,6 +9,15 @@ matches the "MoE 64e top-6" header we follow).  First layer dense (d_ff
 LeoAM adaptation: KV abstracts are min/max boxes over the *compressed latent*
 c_kv (rank 512) + the shared rope key; bounds are computed in latent space
 after absorbing W_UK into the query (DESIGN.md §4).
+
+This entry holds every expert and renormalises the top-k routing weights
+(``MoECfg.norm_topk`` at its default), although the published config sets
+``norm_topk_prob`` false: the benchmark harness describes the small
+variant its tests drive with ``norm_topk_prob`` true
+(``bench/cell.py:conf_of_arch``).  The benchmark's configuration
+(``bench/configs/dsv2-lite-16b-ep8.json``) sets the published routing,
+chip 0's share of the experts and the published ``rms_norm_eps`` (1e-6;
+the default here is 1e-5) through its ``program.overrides``.
 """
 
 from repro.configs.base import RuntimeCfg, ArchConfig, MLACfg, MoECfg

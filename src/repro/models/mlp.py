@@ -6,12 +6,16 @@ by expert id, ranked within their expert, and scattered into (E, C) slots —
 index arrays only, static shapes, capacity drops are explicit.  Expert
 matmuls run as (E, C, d) einsums with the expert dim sharded over the
 ``model``/EP axis.
+
+A layer may hold one share of the experts (``MoECfg.router_width``,
+``first_expert``): it routes over every published expert and computes
+only the picks that land on the experts it holds.
 """
 
 from __future__ import annotations
 
 import math
-from typing import Dict, Optional, Tuple
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -65,7 +69,7 @@ def moe_params(cfg: ArchConfig) -> Dict[str, ParamDef]:
     m, d = cfg.moe, cfg.d_model
     ff = m.d_ff_expert
     p = {
-        "router": ParamDef((d, m.n_experts), ("embed", None), dtype="float32"),
+        "router": ParamDef((d, m.n_routed), ("embed", None), dtype="float32"),
         "w_up": ParamDef((m.n_experts, d, ff), ("expert", "embed", None)),
         "w_down": ParamDef((m.n_experts, ff, d), ("expert", None, "embed")),
     }
@@ -78,13 +82,29 @@ def moe_params(cfg: ArchConfig) -> Dict[str, ParamDef]:
 
 
 def _capacity(tokens: int, m) -> int:
-    c = int(math.ceil(tokens * m.top_k / m.n_experts * m.capacity_factor))
+    c = int(math.ceil(tokens * m.top_k / m.n_routed * m.capacity_factor))
     return max(4, c)
 
 
+#: the dispatch counts ``moe_apply(..., counts=True)`` returns, in order
+MOE_COUNTS = ("moe_picks_held", "moe_rows", "moe_experts_hit")
+
+
 def moe_apply(p, cfg: ArchConfig, x: jax.Array, *, no_drop: bool = False,
-              ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+              counts: bool = False):
     """x: (B, S, d) -> (B, S, d), aux metrics (load-balance/z losses).
+
+    Routing: a float32 softmax over all ``m.n_routed`` experts the router
+    scores, greedy top-k, the weights renormalised over the k picks only
+    under ``m.norm_topk``.  Picks that land on the experts held here
+    (``m.first_expert`` on, ``m.n_experts`` of them) are dispatched to
+    their slots; picks on experts held elsewhere add nothing here.  The
+    shared experts are added once; the aux losses see the whole router.
+
+    ``counts=True`` also returns an int32 vector in :data:`MOE_COUNTS`
+    order: (token, expert) picks on held experts, rows the held experts
+    multiplied (slots, including empty ones) and held experts with at
+    least one pick.
 
     ``no_drop=True`` is the INFERENCE dispatch: expert capacity is raised
     to the worst case (every token to one expert) so no token is ever
@@ -118,23 +138,26 @@ def moe_apply(p, cfg: ArchConfig, x: jax.Array, *, no_drop: bool = False,
     m = cfg.moe
     B, S, d = x.shape
     T = B * S
-    E, K = m.n_experts, m.top_k
+    E, K, R = m.n_experts, m.top_k, m.n_routed
     xf = x.reshape(T, d)
 
     logits = (xf.astype(jnp.float32) @ p["router"].astype(jnp.float32))
-    probs = jax.nn.softmax(logits, axis=-1)                   # (T, E)
+    probs = jax.nn.softmax(logits, axis=-1)                   # (T, R)
     top_p, top_e = jax.lax.top_k(probs, K)                    # (T, K)
-    top_p = top_p / jnp.sum(top_p, -1, keepdims=True)         # renormalize
+    if m.norm_topk:
+        top_p = top_p / jnp.sum(top_p, -1, keepdims=True)     # renormalize
 
     C = T if no_drop else _capacity(T, m)
     # ---- sort-based dispatch ----
-    e_flat = top_e.reshape(-1)                                # (T*K,)
+    e_loc = top_e - m.first_expert                            # held ids
+    held = (e_loc >= 0) & (e_loc < E)
+    e_flat = jnp.where(held, e_loc, E).reshape(-1)            # (T*K,); E: absent
     order = jnp.argsort(e_flat, stable=True)
     sorted_e = e_flat[order]
     # rank within expert: position - first-occurrence(expert)
-    starts = jnp.searchsorted(sorted_e, jnp.arange(E), side="left")
+    starts = jnp.searchsorted(sorted_e, jnp.arange(E + 1), side="left")
     rank = jnp.arange(T * K) - starts[sorted_e]
-    keep = rank < C
+    keep = (rank < C) & (sorted_e < E)
     slot = jnp.where(keep, sorted_e * C + rank, E * C)        # overflow -> sink
     token_of = order // K
 
@@ -162,14 +185,21 @@ def moe_apply(p, cfg: ArchConfig, x: jax.Array, *, no_drop: bool = False,
         y = y + dense_apply(sp, cfg, xf)
 
     # aux losses (Switch-style load balance + router z-loss)
-    onehot = jax.nn.one_hot(top_e, E, dtype=jnp.float32)       # (T,K,E)
+    onehot = jax.nn.one_hot(top_e, R, dtype=jnp.float32)       # (T,K,R)
     frac_tokens = jnp.mean(jnp.sum(onehot, 1), 0)              # f_e
     frac_probs = jnp.mean(probs, 0)                            # P_e
-    lb_loss = E * jnp.sum(frac_tokens * frac_probs)
+    lb_loss = R * jnp.sum(frac_tokens * frac_probs)
     z_loss = jnp.mean(jnp.square(jax.nn.logsumexp(logits, -1)))
-    dropped = 1.0 - jnp.mean(keep.astype(jnp.float32))
+    # picks on experts held elsewhere are not dropped here
+    kept = jnp.where(sorted_e < E, keep, True)
+    dropped = 1.0 - jnp.mean(kept.astype(jnp.float32))
     aux = {"moe_lb_loss": lb_loss, "moe_z_loss": z_loss, "moe_drop_frac": dropped}
-    return y.reshape(B, S, d), aux
+    if not counts:
+        return y.reshape(B, S, d), aux
+    per_expert = starts[1:] - starts[:-1]                      # picks per held
+    n = jnp.stack([jnp.sum(held.astype(jnp.int32)), jnp.int32(E * C),
+                   jnp.sum((per_expert > 0).astype(jnp.int32))])
+    return y.reshape(B, S, d), aux, n
 
 
 def moe_loss(aux: Dict[str, jax.Array], cfg: ArchConfig) -> jax.Array:

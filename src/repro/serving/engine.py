@@ -112,6 +112,7 @@ from repro.core.tiers import AccessTable
 from repro.kernels.pq import adc_chunk_scores
 from repro.models import lm
 from repro.models import attention as attn_mod
+from repro.models import mlp as mlp_mod
 from repro.serving.faults import AdmissionError, ChunkLostError
 from repro.serving.offload import DEVICE, DISK, HOST, TieredKVStore
 from repro.serving.sanitizer import decode_thread_only, worker_thread
@@ -444,6 +445,16 @@ def _post_attention(cfg: ArchConfig, mlp_kind: str, blk, h, y) -> jax.Array:
     return h
 
 
+def _post_attention_moe(cfg: ArchConfig, blk, h, y, counts):
+    """:func:`_post_attention` of an expert layer that also adds its
+    dispatch counts (``mlp.MOE_COUNTS``, int32) to the running ``counts``."""
+    x = h + y
+    out, _, n = mlp_mod.moe_apply(
+        blk["mlp"], cfg, attn_mod.rms_norm(x, blk["ln2"], cfg.norm_eps),
+        no_drop=True, counts=True)
+    return x + out, counts + n
+
+
 def _layer_fn(cfg: ArchConfig, which: str, stacked: bool, mlp_kind: str):
     """``(weights, r, h, pos | y)`` -> :func:`_pre_attention` (``which=
     "pre"``) or :func:`_post_attention` (``"post"``) of one layer.  With
@@ -452,7 +463,9 @@ def _layer_fn(cfg: ArchConfig, which: str, stacked: bool, mlp_kind: str):
     the program, where XLA fuses or elides the copy.  Otherwise the
     weights are a prologue block and ``r`` is None.  The functions' names
     name the programs in a profiler trace (``jit_pre_attention``,
-    ``jit_post_attention``)."""
+    ``jit_post_attention``, and for an expert layer
+    ``jit_post_attention_moe``, which given the round's running dispatch
+    counts returns ``(h, counts)``)."""
     def pick(w, r):
         return jax.tree.map(lambda a: a[r], w) if stacked else w
 
@@ -462,7 +475,14 @@ def _layer_fn(cfg: ArchConfig, which: str, stacked: bool, mlp_kind: str):
     def post_attention(w, r, h, y):
         return _post_attention(cfg, mlp_kind, pick(w, r), h, y)
 
-    return pre_attention if which == "pre" else post_attention
+    def post_attention_moe(w, r, h, y, counts=None):
+        if counts is None:
+            return post_attention(w, r, h, y)
+        return _post_attention_moe(cfg, pick(w, r), h, y, counts)
+
+    if which == "pre":
+        return pre_attention
+    return post_attention_moe if mlp_kind == "moe" else post_attention
 
 
 class BatchedLeoAMEngine:
@@ -558,6 +578,8 @@ class BatchedLeoAMEngine:
         self._layer_programs: Dict[Tuple[str, str, int], Any] = {}
         self._repeat_idx = [jnp.asarray(r, jnp.int32)
                             for r in range(lm._layer_plan(cfg)[2])]
+        # a round's expert layers add their dispatch counts to this
+        self._moe_zero = jnp.zeros(len(mlp_mod.MOE_COUNTS), jnp.int32)
         self._round_idx = 0
         # fault domain: per-seq terminal failure reasons (scheduler pops
         # them after each round) + engine-level counters
@@ -1405,11 +1427,13 @@ class BatchedLeoAMEngine:
                        "body": list(s.cache["body"])} for s in states]
         pos = jnp.asarray(lengths[:, None], jnp.int32)       # (B, 1)
         lengths_j = jnp.asarray(lengths.astype(np.int32))    # (B,)
+        # the expert layers' dispatch counts, summed on device
+        moe_counts = self._moe_zero
 
         def run_attn(w, r, where, pi, mlpk, h, layer_idx):
             """One attention layer; ``w``/``r``/``where``/``pi`` pick its
             programs' weights (see :meth:`_layer_program`)."""
-            nonlocal li
+            nonlocal li, moe_counts
             with span("leoam.qkv"):
                 a = self._layer_program("pre", where, pi, mlpk)(w, r, h, pos)
             with span("leoam.sync"):
@@ -1518,7 +1542,10 @@ class BatchedLeoAMEngine:
                                                seqs=order)
             li += 1
             with span("leoam.mlp"):
-                h = self._layer_program("post", where, pi, mlpk)(w, r, h, y)
+                post = self._layer_program("post", where, pi, mlpk)
+                if mlpk != "moe":
+                    return post(w, r, h, y)
+                h, moe_counts = post(w, r, h, y, moe_counts)
             return h
 
         def run_other(blk, kind, mlpk, h, layer_idx, cache_slices):
@@ -1570,7 +1597,11 @@ class BatchedLeoAMEngine:
         with span("leoam.logits"):
             logits = lm._logits(params, cfg, h)[:, 0]
         with span("leoam.sync"):
-            logits = np.asarray(logits)                      # (B, V)
+            if moe_counts is self._moe_zero:
+                logits = np.asarray(logits)                  # (B, V)
+            else:       # one read: the counts are ready before the logits
+                logits, n = jax.device_get((logits, moe_counts))
+                prof.update(zip(mlp_mod.MOE_COUNTS, map(int, n)))
         total_s = time.perf_counter() - t_round
         prof["total_s"] = total_s
         if not ecfg.profile:

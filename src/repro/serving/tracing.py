@@ -14,6 +14,20 @@ and the backend compiles made on its thread (``compiles``, ``compile_s``,
 The engine merges :meth:`Round.profile` into the round's
 ``round_profiles`` entry.
 
+A round whose attention layers include expert layers also carries their
+dispatch counts (``models.mlp.MOE_COUNTS``), summed over those layers on
+the device and read with the round's logits, in the same transfer:
+
+* ``moe_picks_held``: (token, expert) routing picks that land on the
+  experts this layer holds (of ``top_k`` per token over all the experts
+  the router scores);
+* ``moe_rows``: rows the held experts multiplied, empty dispatch slots
+  included (the drop-free dispatch gives every held expert one slot per
+  token);
+* ``moe_experts_hit``: held experts with at least one pick.
+
+A round with no expert layer has none of these keys.
+
 The spans are always on: with no trace active an annotation and a clock
 pair cost about a microsecond, against a decode round of a thousand or
 more microseconds per layer.

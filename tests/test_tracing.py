@@ -176,3 +176,122 @@ def test_spans_outside_a_round_only_annotate():
     prof = rnd.profile()
     assert prof["compiles"] == 0 and prof["compiles_by_phase"] == {}
     assert tracing._local.round is None and tracing._local.stack == []
+
+
+# ---------------------------------------------------------------------------
+# Expert layers' dispatch counts
+# ---------------------------------------------------------------------------
+
+def _dsv2(**over):
+    """DeepSeek-V2-Lite's small variant holding experts 4..7 of a router
+    over 16, top-4, the routing weights not renormalised."""
+    cfg = get_config("deepseek-v2-lite-16b", smoke=True)
+    cfg = dataclasses.replace(
+        cfg, moe=dataclasses.replace(cfg.moe, n_experts=4, top_k=4,
+                                     router_width=16, first_expert=4,
+                                     norm_topk=False),
+        leoam=dataclasses.replace(cfg.leoam, chunk_size=16,
+                                  importance_rate=0.4, early_rate=0.6,
+                                  min_seq_for_sparse=32), **over)
+    return cfg, lm.init(cfg, jax.random.PRNGKey(2))
+
+
+def _plain_counts(cfg, blk, h, y):
+    """The counts of one expert layer from a plain top-k of its router
+    logits."""
+    from repro.models.common import rms_norm
+    m = cfg.moe
+    x = np.asarray(rms_norm(h + y, blk["ln2"], cfg.norm_eps), np.float64)
+    logits = x[:, 0] @ np.asarray(blk["mlp"]["router"], np.float64)
+    top = np.argsort(-logits, axis=-1, kind="stable")[:, :m.top_k]
+    local = top - m.first_expert
+    held = (local >= 0) & (local < m.n_experts)
+    return {"moe_picks_held": int(held.sum()),
+            "moe_rows": m.n_experts * x.shape[0],
+            "moe_experts_hit": len(set(local[held].tolist()))}
+
+
+def test_an_expert_round_counts_its_dispatch(rng, monkeypatch):
+    from repro.models.mlp import MOE_COUNTS
+    cfg, params = _dsv2()
+    eng, toks = _engine(cfg, params, rng)
+    program = eng._layer_program
+    want = []
+
+    def spy(which, where, pi, mlpk):
+        fn = program(which, where, pi, mlpk)
+        if which != "post" or mlpk != "moe":
+            return fn
+
+        def post(w, r, h, y, counts=None):
+            blk = w if r is None else jax.tree.map(lambda a: a[r], w)
+            want.append(_plain_counts(cfg, blk, h, y))
+            return fn(w, r, h, y, counts)
+        return post
+
+    monkeypatch.setattr(eng, "_layer_program", spy)
+    for _ in range(2):
+        want.clear()
+        toks = eng.decode_round(toks)
+        n_moe = sum(m == "moe" for m in cfg.mlp_kinds())
+        assert len(want) == n_moe >= 2
+        prof = eng.round_profiles[-1]
+        for k in MOE_COUNTS:
+            assert prof[k] == sum(c[k] for c in want), k
+        assert prof["moe_rows"] == n_moe * 4 * len(PROMPTS)
+        assert prof["moe_picks_held"] > 0
+    eng.store.close()
+
+
+def test_a_dense_round_has_no_expert_counts(setup, rng):
+    from repro.models.mlp import MOE_COUNTS
+    cfg, params = setup
+    eng, toks = _engine(cfg, params, rng)
+    _decode(eng, toks, 2)
+    for prof in eng.round_profiles:
+        assert not set(MOE_COUNTS) & set(prof)
+    eng.store.close()
+
+
+def _reads_per_round(cfg, params, monkeypatch):
+    """Device->host reads the engine makes in each of three rounds: one
+    per ``jax.device_get`` call, whatever it holds, and one per
+    ``np.asarray`` of a device array."""
+    import repro.serving.engine as engine_mod
+    reads = [0]
+
+    class CountedNumpy:
+        def __getattr__(self, name):
+            return getattr(np, name)
+
+        def asarray(self, a, *args, **kw):
+            reads[0] += isinstance(a, jax.Array)
+            return np.asarray(a, *args, **kw)
+
+    def counted_get(x, _get=jax.device_get):
+        reads[0] += 1
+        return _get(x)
+
+    eng, toks = _engine(cfg, params, np.random.RandomState(4))
+    monkeypatch.setattr(engine_mod, "np", CountedNumpy())
+    monkeypatch.setattr(jax, "device_get", counted_get)
+    out = []
+    for _ in range(3):
+        reads[0] = 0
+        toks = eng.decode_round(toks)
+        out.append(reads[0])
+    monkeypatch.undo()
+    eng.store.close()
+    return out
+
+
+def test_expert_counts_add_no_device_read(monkeypatch):
+    """A round of the expert stack reads the device as often as the same
+    stack with dense MLPs: the counts come back with the logits."""
+    experts = _dsv2()
+    dense = _dsv2(mlp_pattern=("dense",))
+    assert "moe" in experts[0].mlp_kinds()
+    assert "moe" not in dense[0].mlp_kinds()
+    got = _reads_per_round(*experts, monkeypatch)
+    assert got == _reads_per_round(*dense, monkeypatch)
+    assert min(got) > 0
